@@ -25,7 +25,7 @@ from typing import Callable, Dict
 import jax
 import jax.numpy as jnp
 
-from repro.core.hardware import (TPU_V5E_HBM_BW, TPU_V5E_PEAK_BF16,
+from repro.core.hardware import (TPU_V5E_KIND, chip_peaks,
                                  profile_from_throughput)
 from repro.core.measured_cost import (build_latency_tables, fit_roofline,
                                       probe_kernels)
@@ -33,6 +33,7 @@ from repro.core.measured_cost import (build_latency_tables, fit_roofline,
 SCHEMA = "bench-kernels/v1"
 DEFAULT_TABLE_BATCH = 4     # SimParams.mini_batch — the fleet workload shape
 DEFAULT_TABLE_SEQ = 512     # SimParams.seq_len
+V5E = chip_peaks(TPU_V5E_KIND)  # the chip the kernels are designed against
 
 
 def _time(fn: Callable, reps: int = 3) -> float:
@@ -59,7 +60,7 @@ def bench_lora_matmul() -> Dict:
     hbm_saved = 2 * m * r * 4
     return {"name": "lora_matmul_512", "us_interpret": t_kernel,
             "us_jnp_ref": t_ref,
-            "tpu_compute_bound_us": flops / TPU_V5E_PEAK_BF16 * 1e6,
+            "tpu_compute_bound_us": flops / V5E.bf16_flops_per_s * 1e6,
             "hbm_bytes_saved_by_fusion": hbm_saved}
 
 
@@ -115,7 +116,7 @@ def bench_flash_decode() -> Dict:
     cache_bytes = 2 * b * s * hkv * d * 2  # one HBM sweep (bf16), the bound
     return {"name": "flash_decode_1k", "us_interpret": t_kernel,
             "us_jnp_ref": t_ref,
-            "tpu_bandwidth_bound_us": cache_bytes / TPU_V5E_HBM_BW * 1e6}
+            "tpu_bandwidth_bound_us": cache_bytes / V5E.hbm_bytes_per_s * 1e6}
 
 
 def run(*, smoke: bool = False, reps: int = 3) -> Dict:

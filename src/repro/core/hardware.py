@@ -186,9 +186,38 @@ TPU_V5E_CHIP = DeviceProfile(
     f_max=0.94 * GIGA, delta=8.0, sigma=26_214,  # 0.94e9*8*26214 ~= 197e12
     f_min=0.1 * GIGA, xi=2.4e-25, mem_bytes=16e9)
 
-TPU_V5E_HBM_BW = 819e9        # bytes/s
-TPU_V5E_ICI_BW = 50e9         # bytes/s per link
-TPU_V5E_PEAK_BF16 = 197e12    # FLOP/s
+
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    """Published peaks of one accelerator chip."""
+    bf16_flops_per_s: float
+    hbm_bytes_per_s: float
+    ici_bytes_per_s_per_link: float
+    hbm_bytes: float
+
+
+# What JAX reports as ``device_kind`` for a TPU v5e chip.
+TPU_V5E_KIND = "TPU v5 lite"
+
+# Keyed by ``jax.Device.device_kind``. Source: Google Cloud documentation,
+# "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s, and 1,600 Gbit/s
+# of chip-to-chip interconnect over the 4 links of a 2-D torus.
+CHIP_PEAKS: Dict[str, ChipPeaks] = {
+    TPU_V5E_KIND: ChipPeaks(bf16_flops_per_s=197e12, hbm_bytes_per_s=819e9,
+                            ici_bytes_per_s_per_link=50e9, hbm_bytes=16e9),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The published peaks of ``device_kind``; a chip that is not in
+    ``CHIP_PEAKS`` is an error, never a default."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r} (known: {sorted(CHIP_PEAKS)})"
+                         ) from None
 
 
 def tpu_pod_profile(chips: int) -> DeviceProfile:

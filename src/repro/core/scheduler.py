@@ -234,8 +234,6 @@ def _simulate_fleet_sharded(cfg: ModelConfig, *, mesh, policy: str,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.launch.mesh import fleet_shard_map
-
     nd = len(devices)
     batch = draw_channel_matrix(channel_state, rounds, nd, seed=seed,
                                 bandwidth_hz=sim.bandwidth_hz,
@@ -285,9 +283,9 @@ def _simulate_fleet_sharded(cfg: ModelConfig, *, mesh, policy: str,
     # engine — wrapping the shard_map in another jit would inline that jit
     # and let XLA re-fuse the grid differently (one-ulp drift in the logs),
     # breaking the bit-identity contract this engine is tested against
-    sharded = fleet_shard_map(_decide, mesh,
-                              in_specs=(specs, P(None, "data")),
-                              out_specs=P(None, "data"))
+    sharded = jax.shard_map(_decide, mesh=mesh,
+                            in_specs=(specs, P(None, "data")),
+                            out_specs=P(None, "data"))
     host = jax.device_get(sharded(bctx, draws))
     trim = {f: np.asarray(getattr(host, f))[:, :nd]
             for f in ("cuts", "freqs", "delays", "energies",

@@ -1,8 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode —
-the kernel body runs in Python per grid cell, which validates the exact TPU
-program logic. On a real TPU backend ``interpret=False`` compiles to Mosaic.
+On the CPU backend the kernels execute in ``interpret=True`` mode — the
+kernel body runs in Python per grid cell, which validates the exact TPU
+program logic. Every other backend compiles them to Mosaic, and fails if
+they do not compile: a kernel never runs interpreted on an accelerator.
 """
 from __future__ import annotations
 
@@ -18,7 +19,7 @@ from repro.kernels import ssd_scan as _ssd
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def lora_matmul(x: jax.Array, w: jax.Array, a: jax.Array, b: jax.Array,

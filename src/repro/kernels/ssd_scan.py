@@ -32,11 +32,18 @@ def _kernel(xt_ref, a_ref, b_ref, c_ref, y_ref, st_ref, dec_ref, *,
     B = b_ref[0, 0].astype(jnp.float32)            # (cl, ns)
     C = c_ref[0, 0].astype(jnp.float32)            # (cl, ns)
 
-    cum = jnp.cumsum(a[:, 0])                      # (cl,)
-    seg = cum[:, None] - cum[None, :]              # (cl, cl)
+    # Inclusive prefix sums of the log-decays as masked reductions over the
+    # (cl, cl) tile: Mosaic has no cumsum lowering, but lowers broadcasts,
+    # selects and row/column sums. cum_row[0, j] = sum_{k<=j} a_k; the
+    # column form is read off its diagonal, which avoids a transpose.
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    decay = jnp.where(ii >= jj, jnp.exp(seg), 0.0)
+    cum_row = jnp.sum(jnp.where(ii <= jj, a, 0.0), axis=0,
+                      keepdims=True)               # (1, cl)
+    cum = jnp.sum(jnp.where(ii == jj, cum_row, 0.0), axis=1,
+                  keepdims=True)                   # (cl, 1)
+    cum_last = jnp.sum(a, axis=0, keepdims=True)   # (1, 1)
+    decay = jnp.where(ii >= jj, jnp.exp(cum - cum_row), 0.0)
 
     scores = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -46,17 +53,16 @@ def _kernel(xt_ref, a_ref, b_ref, c_ref, y_ref, st_ref, dec_ref, *,
     y_ref[0, 0] = y.astype(y_ref.dtype)
 
     # chunk-final state: sum_j exp(cum_last - cum_j) B_j (x) xt_j
-    dec_end = jnp.exp(cum[-1] - cum)               # (cl,)
-    bw = B * dec_end[:, None]                      # (cl, ns)
+    bw = B * jnp.exp(cum_last - cum)               # (cl, ns)
     st = jax.lax.dot_general(bw, xt, (((0,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
     st_ref[0, 0] = st.astype(st_ref.dtype)         # (ns, hp)
 
     # decay vectors for the jnp cross-chunk correction:
     #   dec[:, 0] = exp(cum)  (applied to h_prev),  dec[:, 1] = total decay
-    dec_ref[0, 0, :, 0] = jnp.exp(cum).astype(dec_ref.dtype)
-    dec_ref[0, 0, :, 1] = jnp.full((chunk,), jnp.exp(cum[-1]),
-                                   dec_ref.dtype)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (chunk, 2), 1)
+    dec_ref[0, 0] = jnp.where(lane == 0, jnp.exp(cum),
+                              jnp.exp(cum_last)).astype(dec_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -6,8 +6,8 @@ Sources (§Roofline in EXPERIMENTS.md):
     all-gather / all-reduce / reduce-scatter / all-to-all /
     collective-permute result size (bytes moved per device, SPMD view)
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16/chip, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Hardware peaks come from ``core.hardware.chip_peaks``, keyed by device
+kind; the production meshes are TPU v5e chips.
 """
 from __future__ import annotations
 
@@ -15,14 +15,15 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, Tuple
 
-from repro.core.hardware import (TPU_V5E_HBM_BW, TPU_V5E_ICI_BW,
-                                 TPU_V5E_PEAK_BF16)
+from repro.core.hardware import TPU_V5E_KIND, chip_peaks
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
     "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8, "c64": 8,
     "c128": 16,
 }
+
+_V5E = chip_peaks(TPU_V5E_KIND)   # the chips of the production meshes
 
 COLLECTIVE_KINDS = ("all-gather", "all-reduce", "reduce-scatter",
                     "all-to-all", "collective-permute")
@@ -87,15 +88,16 @@ class Roofline:
 
     @property
     def compute_s(self) -> float:
-        return self.flops / TPU_V5E_PEAK_BF16
+        return self.flops / _V5E.bf16_flops_per_s
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes / TPU_V5E_HBM_BW
+        return self.hbm_bytes / _V5E.hbm_bytes_per_s
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes / (TPU_V5E_ICI_BW * self.ici_links)
+        return self.collective_bytes / (_V5E.ici_bytes_per_s_per_link
+                                        * self.ici_links)
 
     @property
     def dominant(self) -> str:
@@ -125,8 +127,6 @@ def model_flops(cfg, tokens: int, kind: str) -> float:
 
 def analyze_compiled(compiled, lowered_text: str, chips: int) -> Tuple[Roofline, CollectiveStats, Dict]:
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0]
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))
     coll = parse_collectives(lowered_text)

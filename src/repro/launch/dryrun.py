@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production meshes, with ShapeDtypeStruct inputs (no allocation).
 
@@ -10,29 +7,34 @@ the production meshes, with ShapeDtypeStruct inputs (no allocation).
 Per combo this prints/records: memory_analysis (fits?), cost_analysis
 (FLOPs/bytes for §Roofline), and the collective schedule parsed from the
 lowered HLO. Failures here are bugs in the sharding config.
+
+The production meshes need 512 devices; ``main`` asks the CPU backend for
+them before JAX starts. A caller of ``lower_combo`` sets
+``--xla_force_host_platform_device_count`` itself.
 """
-import argparse     # noqa: E402
-import dataclasses as _dc  # noqa: E402
-import json         # noqa: E402
-import sys          # noqa: E402
-import time         # noqa: E402
-import traceback    # noqa: E402
-from typing import Dict, Optional  # noqa: E402
+import argparse
+import dataclasses as _dc
+import json
+import os
+import sys
+import time
+import traceback
+from typing import Dict, Optional
 
-import jax          # noqa: E402
-import jax.numpy as jnp  # noqa: E402
+import jax
+import jax.numpy as jnp
 
-from repro.configs.base import (ARCH_IDS, INPUT_SHAPES, InputShape,  # noqa: E402
+from repro.configs.base import (ARCH_IDS, INPUT_SHAPES, InputShape,
                                 ModelConfig, get_config,
                                 long_context_variant)
-from repro.data.pipeline import batch_specs  # noqa: E402
-from repro.launch import sharding as shd  # noqa: E402
-from repro.launch.analysis import analyze_compiled, model_flops  # noqa: E402
-from repro.launch.mesh import make_production_mesh  # noqa: E402
-from repro.launch.serve import make_prefill_step, make_serve_step  # noqa: E402
-from repro.launch.train import make_train_step  # noqa: E402
-from repro.models import model as model_lib  # noqa: E402
-from repro.optim import adamw, constant_schedule  # noqa: E402
+from repro.data.pipeline import batch_specs
+from repro.launch import sharding as shd
+from repro.launch.analysis import analyze_compiled, model_flops
+from repro.launch.mesh import make_production_mesh
+from repro.launch.serve import make_prefill_step, make_serve_step
+from repro.launch.train import make_train_step
+from repro.models import model as model_lib
+from repro.optim import adamw, constant_schedule
 
 DRYRUN_ARCHS = tuple(a for a in ARCH_IDS if a != "llama32-1b")
 
@@ -203,6 +205,9 @@ def lower_combo(arch: str, shape_name: str, *, multi_pod: bool = False,
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+        os.environ.get("XLA_FLAGS"),
+        "--xla_force_host_platform_device_count=512"]))
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default=None)
     p.add_argument("--shape", default=None)

@@ -3,35 +3,33 @@
 Single pod: 16 x 16 = 256 TPU-v5e chips, axes ("data", "model").
 Multi-pod:  2 x 16 x 16 = 512 chips, axes ("pod", "data", "model") — the
 "pod" axis is data-parallel across pods (each pod serves one group of edge
-devices in the SL deployment; DESIGN.md §3).
+devices in the SL deployment).
 
 ``make_production_mesh`` is a function (not a module constant) so importing
 this module never touches jax device state.
+
+Every mesh here uses ``AxisType.Auto`` axes: the models place arrays with
+``PartitionSpec`` constraints and leave the rest to GSPMD propagation, which
+``jax.make_mesh``'s default of explicit axes would reject.
 """
 from __future__ import annotations
 
-from typing import Tuple
-
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
-def make_abstract_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Device-free mesh for sharding-rule checks, across JAX API revisions:
-    0.4.x takes ((name, size), ...) pairs; newer takes (sizes, names)."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape,
-                                                   strict=True)))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
-
-
-def data_axes(mesh) -> Tuple[str, ...]:
+def data_axes(mesh) -> tuple[str, ...]:
     """Axes that shard the batch dimension."""
     return tuple(a for a in mesh.axis_names if a in ("pod", "data"))
 
@@ -42,7 +40,7 @@ def model_axis(mesh) -> str:
 
 def make_debug_mesh(shape=(1, 1), axes=("data", "model")):
     """A CPU-sized mesh for tests."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_fleet_mesh(n_shards: int = 0):
@@ -53,15 +51,4 @@ def make_fleet_mesh(n_shards: int = 0):
     """
     if n_shards <= 0:
         n_shards = len(jax.devices())
-    return jax.make_mesh((n_shards,), ("data",))
-
-
-def fleet_shard_map(fn, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across JAX API revisions (0.4.x keeps it under
-    ``jax.experimental.shard_map`` with ``check_rep`` instead of
-    ``check_vma``) — same shim as ``models/moe_shard_map.py``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _esm
-    return _esm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    return _auto_mesh((n_shards,), ("data",))

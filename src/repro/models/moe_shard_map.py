@@ -42,16 +42,6 @@ from repro.configs.base import ModelConfig
 from repro.models.common import ACC_DTYPE, Params, silu
 from repro.models.moe import group_capacity, ranks_within_groups
 
-if hasattr(jax, "shard_map"):                      # jax >= 0.6
-    _shard_map = jax.shard_map
-else:                                              # 0.4.x: experimental home,
-    from jax.experimental.shard_map import shard_map as _esm
-
-    def _shard_map(fn, *, mesh, in_specs, out_specs, check_vma=True):
-        return _esm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_rep=check_vma)           # check_vma was check_rep
-
-
 def select_strategy(cfg: ModelConfig) -> Optional[str]:
     """Pick the distributed MoE layout for the active mesh (None => jnp/GSPMD
     path, used on CPU and single-device tests)."""
@@ -305,7 +295,7 @@ def moe_forward_dist(params: Params, lora: Optional[Params], x: jax.Array,
                 shared_spec if shared is not None else None)
     out_specs = (xspec, P())
 
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False)
     out, aux = mapped(x, params["router"], params["w_gate"], params["w_up"],

@@ -107,7 +107,7 @@ def test_multi_shard_subprocess_bit_identical():
     """
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     timeout_s = 560.0
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, timeout=timeout_s,

@@ -2,15 +2,15 @@
 (AbstractMesh — no devices needed, pure divisibility logic)."""
 import jax
 import pytest
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ARCH_IDS, get_config
 from repro.launch import sharding as shd
-from repro.launch.mesh import make_abstract_mesh
 from repro.models import model as M
 
-MESH_1POD = make_abstract_mesh((16, 16), ("data", "model"))
-MESH_2POD = make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH_1POD = AbstractMesh((16, 16), ("data", "model"))
+MESH_2POD = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _axis_product(mesh, axis):

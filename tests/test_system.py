@@ -16,7 +16,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 def _run_py(code: str, timeout_s=560):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     return subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                           capture_output=True, text=True, timeout=timeout_s,
                           env=env)
