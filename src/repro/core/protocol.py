@@ -22,6 +22,7 @@ import math
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core import card as card_lib
@@ -30,7 +31,8 @@ from repro.core.cost_model import RoundContext, Workload
 from repro.core.faults import (CircuitBreaker, ExchangeFailed, FaultInjector,
                                RetryPolicy, retry_call)
 from repro.core.hardware import DeviceProfile, SimParams
-from repro.core.splitting import SplitExecutor
+from repro.core.splitting import (SPAN_BATCH, SPAN_DECIDE, SPAN_LOSS_SYNC,
+                                  SPAN_OPTIMIZER, SPAN_ROUND, SplitExecutor)
 from repro.models.common import Params
 from repro.optim import Optimizer, apply_updates
 
@@ -177,46 +179,53 @@ class SplitFineTuner:
     def run_round(self, n: int, device_idx: int) -> RoundLog:
         """One device's round; raises :class:`ExchangeFailed` if the link
         stays down past the retry budget (caller restores state)."""
-        dev = self.devices[device_idx]
-        chan_state = self.channels[device_idx].draw()
-        workload = Workload(self.cost_cfg, self.sim.mini_batch,
-                            self.sim.seq_len)
-        ctx = RoundContext(workload=workload, device=dev, server=self.server,
-                           channel=chan_state, sim=self.sim)
-        # Stage 1: splitting decision (cut index mapped onto the trained
-        # stack if the cost model uses the full-size config)
-        decision = self._decide(ctx)
-        cut = decision.cut
-        if self.cost_cfg.n_layers != self.cfg.n_layers:
-            cut = round(cut * self.cfg.n_layers / self.cost_cfg.n_layers)
+        with TraceAnnotation(SPAN_ROUND):
+            dev = self.devices[device_idx]
+            with TraceAnnotation(SPAN_DECIDE):
+                chan_state = self.channels[device_idx].draw()
+                workload = Workload(self.cost_cfg, self.sim.mini_batch,
+                                    self.sim.seq_len)
+                ctx = RoundContext(workload=workload, device=dev,
+                                   server=self.server, channel=chan_state,
+                                   sim=self.sim)
+                # Stage 1: splitting decision (cut index mapped onto the
+                # trained stack if the cost model uses the full-size config)
+                decision = self._decide(ctx)
+            cut = decision.cut
+            if self.cost_cfg.n_layers != self.cfg.n_layers:
+                cut = round(cut * self.cfg.n_layers / self.cost_cfg.n_layers)
 
-        # Stages 2-5: T local epochs of real split training; each epoch's
-        # smashed-data/gradient exchange runs under the retry envelope.
-        # Only the last epoch's loss is logged, so the device sync happens
-        # once after the loop instead of serializing every epoch.
-        loss = None
-        attempts = 1
-        backoff_s = 0.0
-        for _ in range(self.sim.local_epochs):
-            batch = self.datasets[device_idx].minibatch(
-                self.sim.mini_batch, self.sim.seq_len)
-            (loss, grads), tries, waited_s = self._exchange(
-                n, device_idx,
-                lambda b=batch: self.executor.step(self.frozen, self.lora,
-                                                   b, cut))
-            attempts = max(attempts, tries)
-            backoff_s += waited_s
-            updates, self.opt_state = self.optimizer.update(
-                grads, self.opt_state, self.lora)
-            self.lora = apply_updates(self.lora, updates)
-        loss_val = float(loss) if loss is not None else float("nan")
+            # Stages 2-5: T local epochs of real split training; each
+            # epoch's smashed-data/gradient exchange runs under the retry
+            # envelope. Only the last epoch's loss is logged, so the device
+            # sync happens once after the loop instead of serializing every
+            # epoch.
+            loss = None
+            attempts = 1
+            backoff_s = 0.0
+            for _ in range(self.sim.local_epochs):
+                with TraceAnnotation(SPAN_BATCH):
+                    batch = self.datasets[device_idx].minibatch(
+                        self.sim.mini_batch, self.sim.seq_len)
+                (loss, grads), tries, waited_s = self._exchange(
+                    n, device_idx,
+                    lambda b=batch: self.executor.step(self.frozen,
+                                                       self.lora, b, cut))
+                attempts = max(attempts, tries)
+                backoff_s += waited_s
+                with TraceAnnotation(SPAN_OPTIMIZER):
+                    updates, self.opt_state = self.optimizer.update(
+                        grads, self.opt_state, self.lora)
+                    self.lora = apply_updates(self.lora, updates)
+            with TraceAnnotation(SPAN_LOSS_SYNC):
+                loss_val = float(loss) if loss is not None else float("nan")
 
-        return RoundLog(round_idx=n, device=dev.name, cut=cut,
-                        frequency=decision.frequency,
-                        delay=decision.delay + backoff_s,
-                        server_energy=decision.energy, loss=loss_val,
-                        cost=decision.cost, attempts=attempts,
-                        backoff_s=backoff_s)
+            return RoundLog(round_idx=n, device=dev.name, cut=cut,
+                            frequency=decision.frequency,
+                            delay=decision.delay + backoff_s,
+                            server_energy=decision.energy, loss=loss_val,
+                            cost=decision.cost, attempts=attempts,
+                            backoff_s=backoff_s)
 
     def _skip_log(self, n: int, device_idx: int, status: str,
                   attempts: int = 0, backoff_s: float = 0.0) -> RoundLog:

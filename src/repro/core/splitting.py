@@ -16,6 +16,14 @@ training dynamics include the quantization error.
 
 A cut is a static argument — each cut compiles its own pair of programs and
 ``SplitExecutor`` memoizes them (cut changes at round granularity, Alg. 1).
+
+Inside ``split_grads`` each stage runs under a ``jax.named_scope`` (the
+``SCOPE_*`` names), which reaches the compiled program's op metadata: the
+backward pass carries the same scope inside ``transpose(...)``. The host
+side of a step and of a device's round opens ``jax.profiler`` spans (the
+``SPAN_*`` names). With the profiler off a scope costs nothing and a span
+next to nothing; a profiler trace reads them to put device time and host
+gaps down to a stage.
 """
 from __future__ import annotations
 
@@ -24,10 +32,35 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.models import model as model_lib
 from repro.models.common import Params, softmax_cross_entropy
+
+
+# ---------------------------------------------------------------------------
+# Trace names of the split path
+# ---------------------------------------------------------------------------
+
+# named scopes inside split_grads, disjoint
+SCOPE_DEVICE_STAGE = "sl.device_stage"    # embedding + layers [0, c)
+SCOPE_LINK = "sl.link"                    # int8 round trip, both ways
+SCOPE_SERVER_LAYERS = "sl.server_layers"  # layers [c, I)
+SCOPE_HEAD = "sl.head"                    # final norm + head + loss
+SCOPES = (SCOPE_DEVICE_STAGE, SCOPE_LINK, SCOPE_SERVER_LAYERS, SCOPE_HEAD)
+
+# host spans; all but SPAN_ROUND nest in it
+SPAN_ROUND = "sl.round"            # SplitFineTuner.run_round
+SPAN_DECIDE = "sl.decide"          # channel draw + cost context + policy
+SPAN_BATCH = "sl.batch"            # one local epoch's minibatch
+SPAN_SPLIT_LORA = "sl.split_lora"  # adapters split at the cut
+SPAN_DISPATCH = "sl.dispatch"      # the split_grads call
+SPAN_MERGE_LORA = "sl.merge_lora"  # gradients merged back
+SPAN_OPTIMIZER = "sl.optimizer"    # optimizer update + apply
+SPAN_LOSS_SYNC = "sl.loss_sync"    # the round's one wait for the device
+SPANS = (SPAN_ROUND, SPAN_DECIDE, SPAN_BATCH, SPAN_SPLIT_LORA, SPAN_DISPATCH,
+         SPAN_MERGE_LORA, SPAN_OPTIMIZER, SPAN_LOSS_SYNC)
 
 
 # ---------------------------------------------------------------------------
@@ -53,9 +86,10 @@ def channel_compress(x: jax.Array, enabled: bool) -> jax.Array:
     """Straight-through int8 round trip emulating the phi-compressed link."""
     if not enabled:
         return x
-    q, s = quantize_int8(x)
-    xq = dequantize_int8(q, s, x.dtype)
-    return x + jax.lax.stop_gradient(xq - x)
+    with jax.named_scope(SCOPE_LINK):
+        q, s = quantize_int8(x)
+        xq = dequantize_int8(q, s, x.dtype)
+        return x + jax.lax.stop_gradient(xq - x)
 
 
 # ---------------------------------------------------------------------------
@@ -83,13 +117,14 @@ def device_forward(frozen: Params, lora_dev: Params, inputs: jax.Array,
                    cfg: ModelConfig, cut: int, *, impl: str = "naive",
                    compress: bool = True) -> jax.Array:
     """Eq. 2: smashed data at the cut layer (embedding + layers [0,c))."""
-    if cut == 0:
-        x = model_lib.embed_inputs(frozen, inputs, cfg)
-    else:
-        lora_full = {"layers": lora_dev["layers"]}
-        x, _ = model_lib.forward_hidden(
-            frozen, lora_full, inputs, cfg, lo=0, hi=cut, impl=impl,
-            remat=False, lora_sliced=True)
+    with jax.named_scope(SCOPE_DEVICE_STAGE):
+        if cut == 0:
+            x = model_lib.embed_inputs(frozen, inputs, cfg)
+        else:
+            lora_full = {"layers": lora_dev["layers"]}
+            x, _ = model_lib.forward_hidden(
+                frozen, lora_full, inputs, cfg, lo=0, hi=cut, impl=impl,
+                remat=False, lora_sliced=True)
     return channel_compress(x, compress)
 
 
@@ -101,11 +136,14 @@ def server_loss(frozen: Params, lora_srv: Params, smashed: jax.Array,
         x, aux = smashed, 0.0
     else:
         lora_full = {"layers": lora_srv["layers"]}
-        x, aux = model_lib.forward_hidden(
-            frozen, lora_full, smashed, cfg, lo=cut, hi=cfg.n_layers,
-            impl=impl, remat=False, inputs_embedded=True, lora_sliced=True)
-    logits = model_lib.logits_from_hidden(frozen, x, cfg)
-    return softmax_cross_entropy(logits, labels) + aux
+        with jax.named_scope(SCOPE_SERVER_LAYERS):
+            x, aux = model_lib.forward_hidden(
+                frozen, lora_full, smashed, cfg, lo=cut, hi=cfg.n_layers,
+                impl=impl, remat=False, inputs_embedded=True,
+                lora_sliced=True)
+    with jax.named_scope(SCOPE_HEAD):
+        logits = model_lib.logits_from_hidden(frozen, x, cfg)
+        return softmax_cross_entropy(logits, labels) + aux
 
 
 # ---------------------------------------------------------------------------
@@ -155,10 +193,14 @@ class SplitExecutor:
     def step(self, frozen: Params, lora: Params, batch: Dict[str, Any],
              cut: int) -> Tuple[jax.Array, Params]:
         """One local epoch: returns (loss, full-model LoRA grads)."""
-        lora_dev, lora_srv = split_lora(lora, cut)
+        with TraceAnnotation(SPAN_SPLIT_LORA):
+            lora_dev, lora_srv = split_lora(lora, cut)
         inputs = (batch["embeds"] if self.cfg.input_mode == "embeds"
                   else batch["tokens"])
-        loss, g_dev, g_srv = split_grads(
-            frozen, lora_dev, lora_srv, inputs, batch["labels"],
-            cfg=self.cfg, cut=cut, impl=self.impl, compress=self.compress)
-        return loss, merge_lora(g_dev, g_srv)
+        with TraceAnnotation(SPAN_DISPATCH):
+            loss, g_dev, g_srv = split_grads(
+                frozen, lora_dev, lora_srv, inputs, batch["labels"],
+                cfg=self.cfg, cut=cut, impl=self.impl,
+                compress=self.compress)
+        with TraceAnnotation(SPAN_MERGE_LORA):
+            return loss, merge_lora(g_dev, g_srv)
