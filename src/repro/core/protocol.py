@@ -10,7 +10,8 @@ Each training round, for each participating device:
            (Stages 3-4 repeat for T local epochs.)
   Stage 5  Device-side adapter upload; server merges R = {R^D;R^S}.
 
-The JAX computation is real (split_grads + optimizer); the wall-clock /
+The JAX computation is real: each local epoch is two compiled programs, the
+split step (``split_grads_full``) and the optimizer update; the wall-clock /
 energy numbers are *simulated* through the paper's cost model driven by the
 same workload constants — this is exactly the paper's methodology (a
 physical 5-Jetson testbed feeding a delay/energy model).
@@ -21,6 +22,7 @@ import dataclasses
 import math
 from typing import Callable, Dict, List, Optional
 
+import jax
 import numpy as np
 from jax.profiler import TraceAnnotation
 
@@ -135,6 +137,14 @@ class SplitFineTuner:
         self.lora = lora
         self.optimizer = optimizer
         self.opt_state = optimizer.init(lora)
+
+        # one program for every cut; no donation, since run() keeps the
+        # state of a round and of a device for rollback
+        def optimizer_step(grads, opt_state, lora):
+            updates, opt_state = optimizer.update(grads, opt_state, lora)
+            return apply_updates(lora, updates), opt_state
+
+        self._optimizer_step = jax.jit(optimizer_step)
         self.devices = devices
         self.server = server
         self.channels = channels
@@ -214,9 +224,8 @@ class SplitFineTuner:
                 attempts = max(attempts, tries)
                 backoff_s += waited_s
                 with TraceAnnotation(SPAN_OPTIMIZER):
-                    updates, self.opt_state = self.optimizer.update(
+                    self.lora, self.opt_state = self._optimizer_step(
                         grads, self.opt_state, self.lora)
-                    self.lora = apply_updates(self.lora, updates)
             with TraceAnnotation(SPAN_LOSS_SYNC):
                 loss_val = float(loss) if loss is not None else float("nan")
 

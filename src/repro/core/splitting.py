@@ -14,8 +14,10 @@ The compression is a straight-through int8 quantizer: the paper models phi
 as a data-size ratio on the link (Eq. 9); here it is also *executed* so the
 training dynamics include the quantization error.
 
-A cut is a static argument — each cut compiles its own pair of programs and
-``SplitExecutor`` memoizes them (cut changes at round granularity, Alg. 1).
+A cut is a static argument — each cut compiles its own program (cut changes
+at round granularity, Alg. 1). ``SplitExecutor.step`` runs one local epoch
+as one program, ``split_grads_full``: the adapters split at the cut, both
+stages, and the gradients merged back.
 
 Inside ``split_grads`` each stage runs under a ``jax.named_scope`` (the
 ``SCOPE_*`` names), which reaches the compiled program's op metadata: the
@@ -54,13 +56,11 @@ SCOPES = (SCOPE_DEVICE_STAGE, SCOPE_LINK, SCOPE_SERVER_LAYERS, SCOPE_HEAD)
 SPAN_ROUND = "sl.round"            # SplitFineTuner.run_round
 SPAN_DECIDE = "sl.decide"          # channel draw + cost context + policy
 SPAN_BATCH = "sl.batch"            # one local epoch's minibatch
-SPAN_SPLIT_LORA = "sl.split_lora"  # adapters split at the cut
-SPAN_DISPATCH = "sl.dispatch"      # the split_grads call
-SPAN_MERGE_LORA = "sl.merge_lora"  # gradients merged back
-SPAN_OPTIMIZER = "sl.optimizer"    # optimizer update + apply
+SPAN_DISPATCH = "sl.dispatch"      # the split_grads_full call
+SPAN_OPTIMIZER = "sl.optimizer"    # the compiled optimizer update + apply
 SPAN_LOSS_SYNC = "sl.loss_sync"    # the round's one wait for the device
-SPANS = (SPAN_ROUND, SPAN_DECIDE, SPAN_BATCH, SPAN_SPLIT_LORA, SPAN_DISPATCH,
-         SPAN_MERGE_LORA, SPAN_OPTIMIZER, SPAN_LOSS_SYNC)
+SPANS = (SPAN_ROUND, SPAN_DECIDE, SPAN_BATCH, SPAN_DISPATCH, SPAN_OPTIMIZER,
+         SPAN_LOSS_SYNC)
 
 
 # ---------------------------------------------------------------------------
@@ -182,25 +182,34 @@ def split_grads(frozen: Params, lora_dev: Params, lora_srv: Params,
 
 
 class SplitExecutor:
-    """Caches compiled split programs per cut (Stage 1 re-splits per round)."""
+    """Runs the split step as one compiled program per cut (Stage 1
+    re-splits per round); the program is named ``jit_split_grads_full``."""
 
     def __init__(self, cfg: ModelConfig, *, impl: str = "naive",
                  compress: bool = True):
         self.cfg = cfg
-        self.impl = impl
-        self.compress = compress
+
+        def split_grads_full(frozen: Params, lora: Params, inputs: jax.Array,
+                             labels: jax.Array, *, cut: int
+                             ) -> Tuple[jax.Array, Params]:
+            """The adapters split at the cut, ``split_grads`` (inlined), the
+            gradients merged back."""
+            lora_dev, lora_srv = split_lora(lora, cut)
+            loss, g_dev, g_srv = split_grads(
+                frozen, lora_dev, lora_srv, inputs, labels, cfg=cfg, cut=cut,
+                impl=impl, compress=compress)
+            return loss, merge_lora(g_dev, g_srv)
+
+        # this executor's own program, traced from the stage functions as
+        # they stand when it first runs at a cut; no donation, since callers
+        # keep the adapters they pass
+        self.compiled_step = jax.jit(split_grads_full, static_argnames="cut")
 
     def step(self, frozen: Params, lora: Params, batch: Dict[str, Any],
              cut: int) -> Tuple[jax.Array, Params]:
         """One local epoch: returns (loss, full-model LoRA grads)."""
-        with TraceAnnotation(SPAN_SPLIT_LORA):
-            lora_dev, lora_srv = split_lora(lora, cut)
         inputs = (batch["embeds"] if self.cfg.input_mode == "embeds"
                   else batch["tokens"])
         with TraceAnnotation(SPAN_DISPATCH):
-            loss, g_dev, g_srv = split_grads(
-                frozen, lora_dev, lora_srv, inputs, batch["labels"],
-                cfg=self.cfg, cut=cut, impl=self.impl,
-                compress=self.compress)
-        with TraceAnnotation(SPAN_MERGE_LORA):
-            return loss, merge_lora(g_dev, g_srv)
+            return self.compiled_step(frozen, lora, inputs, batch["labels"],
+                                      cut=cut)

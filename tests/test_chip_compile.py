@@ -18,7 +18,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import get_config
-from repro.core.splitting import split_grads, split_lora
+from repro.core.splitting import SplitExecutor, split_grads, split_lora
 from repro.kernels import flash_attention as fa
 from repro.kernels import flash_decode as fd
 from repro.kernels import lora_matmul as lm
@@ -128,6 +128,21 @@ def test_split_step_fits_one_chip(one_chip):
     ).lower(_placed(one_chip, params["frozen"]),
             _placed(one_chip, lora_dev), _placed(one_chip, lora_srv),
             tokens, tokens).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert used < HBM_BYTES, f"{used / 1e9:.2f} GB"
+
+
+def test_fused_split_step_fits_one_chip(one_chip):
+    """The program a local epoch runs, ``SplitExecutor``'s step (adapters
+    split at the cut, both stages, gradients merged), at the cut CARD picks
+    for the Table II fleet."""
+    params = model_lib.abstract_params(QWEN)
+    tokens = _shape(one_chip, (4, 512), jnp.int32)
+    compiled = SplitExecutor(QWEN).compiled_step.lower(
+        _placed(one_chip, params["frozen"]), _placed(one_chip, params["lora"]),
+        tokens, tokens, cut=0).compile()
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)

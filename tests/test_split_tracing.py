@@ -18,9 +18,8 @@ from repro.core.protocol import SplitFineTuner
 from repro.core.splitting import (SCOPE_DEVICE_STAGE, SCOPE_HEAD,
                                   SCOPE_SERVER_LAYERS, SCOPES, SPAN_BATCH,
                                   SPAN_DECIDE, SPAN_DISPATCH, SPAN_LOSS_SYNC,
-                                  SPAN_MERGE_LORA, SPAN_OPTIMIZER, SPAN_ROUND,
-                                  SPAN_SPLIT_LORA, SPANS, split_grads,
-                                  split_lora)
+                                  SPAN_OPTIMIZER, SPAN_ROUND, SPANS,
+                                  split_grads, split_lora)
 from repro.models import model as M
 from repro.optim import adamw, constant_schedule
 
@@ -47,7 +46,7 @@ def op_names(tiny):
 
 def test_names_are_distinct_and_prefixed():
     names = SCOPES + SPANS
-    assert len(set(names)) == len(names) == 12
+    assert len(set(names)) == len(names) == 10
     assert all(n.startswith("sl.") for n in names)
 
 
@@ -117,8 +116,7 @@ def test_each_span_counts_as_the_protocol_implies(traced_round):
     spans, n_dev, epochs = traced_round
     counts = Counter(name for name, _, _ in spans)
     per_round = {SPAN_ROUND, SPAN_DECIDE, SPAN_LOSS_SYNC}
-    per_epoch = {SPAN_BATCH, SPAN_SPLIT_LORA, SPAN_DISPATCH, SPAN_MERGE_LORA,
-                 SPAN_OPTIMIZER}
+    per_epoch = {SPAN_BATCH, SPAN_DISPATCH, SPAN_OPTIMIZER}
     assert per_round | per_epoch == set(SPANS)
     assert counts == {**{n: n_dev for n in per_round},
                       **{n: n_dev * epochs for n in per_epoch}}
