@@ -6,6 +6,7 @@ exposing ``CONFIG``. The registry resolves ``--arch <id>`` strings.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib
 from dataclasses import dataclass, field, replace
 from typing import Dict, Optional, Tuple
@@ -34,8 +35,10 @@ class ModelConfig:
 
     dense  - GQA transformer decoder (RoPE / SwiGLU)
     moe    - GQA attention + top-k mixture-of-experts MLP
-    ssm    - Mamba2 (SSD) attention-free blocks
+    ssm    - Mamba2 (SSD) blocks, each followed by the MLP when d_ff > 0
     hybrid - parallel attention + Mamba heads per layer (Hymba)
+    mixed  - Mamba2 and attention layers by index (``layer_types``), each
+             followed by the MLP (Granite 4.0-H)
     audio  - dense decoder over precomputed codec-frame embeddings (stub frontend)
     vlm    - dense decoder over precomputed patch embeddings (stub frontend)
     """
@@ -54,6 +57,15 @@ class ModelConfig:
     qkv_bias: bool = False
     rope_theta: float = 10_000.0
     sliding_window: int = 0          # 0 => full causal; >0 => SWA width
+    position_embedding: str = "rope"  # "rope" | "nope" (no positions)
+    attention_multiplier: float = 0.0  # softmax scale; 0 => 1/sqrt(head_dim)
+    # mixed stacks: the kind of each layer by index, "mamba" | "attention"
+    layer_types: Tuple[str, ...] = ()
+    # Granite scaling: embeddings x embedding_multiplier, each mixer's and
+    # MLP's output x residual_multiplier, logits / logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # MoE
     n_experts: int = 0
     top_k: int = 0
@@ -77,6 +89,47 @@ class ModelConfig:
     dtype: str = "bfloat16"
     lora: LoRAConfig = field(default_factory=LoRAConfig)
     source: str = ""        # citation for the assigned config
+
+    def __post_init__(self):
+        # a configuration file gives lists; a static jit argument must hash
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if (self.family == "mixed") != bool(self.layer_types):
+            raise ValueError("layer_types is given for family 'mixed' only")
+        if self.layer_types and (
+                len(self.layer_types) != self.n_layers
+                or not set(self.layer_types) <= set(LAYER_KIND_FAMILY)):
+            raise ValueError(f"layer_types must name {self.n_layers} layers "
+                             f"of kinds {sorted(LAYER_KIND_FAMILY)}")
+
+    # ---- layer kinds -----------------------------------------------------
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """The kind of each layer: ``layer_types`` for a mixed stack, the
+        family for every other."""
+        return self.layer_types or (self.family,) * self.n_layers
+
+    def kind_config(self, kind: str) -> "ModelConfig":
+        """The uniform configuration one layer of ``kind`` runs as: a
+        mixed stack's "mamba" layer is an ``ssm`` layer with its MLP, its
+        "attention" layer a ``dense`` one. A uniform stack is its own."""
+        if not self.layer_types:
+            return self
+        return _kind_view(self, kind)
+
+    def kind_index(self, kind: str, layer: int) -> int:
+        """Position of layer ``layer`` (or of the first layer at or after
+        it) within the stack of its kind: the layers of ``kind`` below it."""
+        if not self.layer_types:
+            return layer
+        return self.layer_types[:layer].count(kind)
+
+    def kind_counts(self, lo: int, hi: int) -> Dict[str, int]:
+        """How many layers of each kind lie in [lo, hi), kinds in order of
+        first appearance."""
+        out: Dict[str, int] = {}
+        for kind in self.layer_kinds[lo:hi]:
+            out[kind] = out.get(kind, 0) + 1
+        return out
 
     # ---- derived ---------------------------------------------------------
     @property
@@ -108,7 +161,7 @@ class ModelConfig:
 
     @property
     def has_ssm(self) -> bool:
-        return self.family in ("ssm", "hybrid")
+        return self.family in ("ssm", "hybrid", "mixed")
 
     @property
     def is_moe(self) -> bool:
@@ -141,29 +194,35 @@ class ModelConfig:
             total = (self.n_experts + self.n_shared_experts) * per_expert
             total += d * self.n_experts  # router
             return total
-        if self.family == "ssm":
-            di, ns = self.ssm_d_inner, self.ssm_state
-            nh = self.ssm_n_heads
-            # in_proj -> (z, x, B, C, dt), conv, dt/A/D, out_proj
-            in_proj = d * (2 * di + 2 * ns + nh)
-            conv = self.ssm_conv_width * (di + 2 * ns)
-            extra = 2 * nh + nh  # A_log, D, dt_bias
-            out_proj = di * d
-            return in_proj + conv + extra + out_proj + di  # + gate norm
         return 3 * d * self.d_ff
 
+    def ssm_in_proj_width(self) -> int:
+        """in_proj's outputs: z, x, B, C and one dt per head."""
+        return 2 * self.ssm_d_inner + 2 * self.ssm_state + self.ssm_n_heads
+
     def ssm_params_per_layer(self) -> int:
-        if self.family != "hybrid":
+        """The Mamba-2 mixer: in_proj, conv weight and bias, A_log, D,
+        dt_bias, the gated norm and out_proj."""
+        if not self.has_ssm:
             return 0
         di, ns, nh = self.ssm_d_inner, self.ssm_state, self.ssm_n_heads
-        in_proj = self.d_model * (2 * di + 2 * ns + nh)
-        conv = self.ssm_conv_width * (di + 2 * ns)
-        return in_proj + conv + 3 * nh + di * self.d_model + di
+        conv = (self.ssm_conv_width + 1) * (di + 2 * ns)
+        return (self.d_model * self.ssm_in_proj_width() + conv + 3 * nh
+                + di + di * self.d_model)
 
     def params_per_layer(self) -> int:
-        norms = 2 * self.d_model
+        """Parameters of one layer; a mixed stack's layers differ by kind,
+        so ask ``kind_config(kind)``."""
+        self._uniform()
+        norms = self.d_model * (2 if self.family != "ssm" or self.d_ff
+                                else 1)   # norm2 precedes an MLP
         return (self.attn_params_per_layer() + self.mlp_params_per_layer()
                 + self.ssm_params_per_layer() + norms)
+
+    def _uniform(self) -> None:
+        if self.layer_types:
+            raise ValueError(f"{self.name} mixes layer kinds; count one "
+                             f"through kind_config(kind)")
 
     def embed_params(self) -> int:
         p = self.vocab_size * self.d_model
@@ -173,7 +232,9 @@ class ModelConfig:
         return p
 
     def total_params(self) -> int:
-        return self.n_layers * self.params_per_layer() + self.embed_params()
+        return sum(n * self.kind_config(k).params_per_layer()
+                   for k, n in self.kind_counts(0, self.n_layers).items()
+                   ) + self.embed_params()
 
     def active_params(self) -> int:
         """Params touched per token (MoE: only routed experts)."""
@@ -184,8 +245,10 @@ class ModelConfig:
         return self.total_params() - self.n_layers * inactive
 
     def lora_params_per_layer(self) -> int:
+        """Adapter parameters of one layer (ask ``kind_config(kind)`` of a
+        mixed stack)."""
+        self._uniform()
         r, d = self.lora.rank, self.d_model
-        hd = self.resolved_head_dim
         total = 0
         t = self.lora.targets
         if not self.is_attention_free:
@@ -202,20 +265,15 @@ class ModelConfig:
             # adapters would defeat PEFT; we adapt the expert-merged output via
             # a single (d,d) adapter pair per layer.
             total += 2 * r * d
-        elif self.family == "ssm":
-            di = self.ssm_d_inner
-            total += r * (d + di) + r * (di + d)  # in/out proj adapters
         else:
-            if "w_gate" in t:
-                total += r * (d + self.d_ff)
-            if "w_up" in t:
-                total += r * (d + self.d_ff)
-            if "w_down" in t:
-                total += r * (self.d_ff + d)
-        if self.family == "hybrid":
-            di = self.ssm_d_inner
-            total += r * (d + di) + r * (di + d)
-        del hd
+            for name, d_in, d_out in (("w_gate", d, self.d_ff),
+                                      ("w_up", d, self.d_ff),
+                                      ("w_down", self.d_ff, d)):
+                if name in t and self.d_ff:
+                    total += r * (d_in + d_out)
+        if self.has_ssm:  # in_proj and out_proj adapters
+            total += (r * (d + self.ssm_in_proj_width())
+                      + r * (self.ssm_d_inner + d))
         return total
 
     # ---- reduced variant for CPU smoke tests ------------------------------
@@ -227,9 +285,12 @@ class ModelConfig:
         n_kv = max(1, min(self.n_kv_heads, 2)) if self.n_kv_heads else 0
         if n_heads and n_kv:
             n_heads = (n_heads // n_kv) * n_kv or n_kv
+        # a mixed stack keeps one layer of each kind, in published order
+        kinds = tuple(dict.fromkeys(self.layer_types))
         return replace(
             self,
-            n_layers=2,
+            n_layers=len(kinds) or 2,
+            layer_types=kinds,
             d_model=d,
             n_heads=n_heads,
             n_kv_heads=n_kv,
@@ -307,7 +368,17 @@ ARCH_IDS = (
     "internvl2-26b",
     "qwen2-7b",
     "llama32-1b",  # the paper's own simulation model (Sec. V)
+    "granite-4.0-h-micro",
 )
+
+# the uniform family each kind of a mixed stack's layers runs as
+LAYER_KIND_FAMILY = {"mamba": "ssm", "attention": "dense"}
+
+
+@functools.lru_cache(maxsize=64)
+def _kind_view(cfg: ModelConfig, kind: str) -> ModelConfig:
+    # CARD prices every cut of every decision through these views
+    return replace(cfg, family=LAYER_KIND_FAMILY[kind], layer_types=())
 
 _MODULE_FOR = {a: a.replace("-", "_").replace(".", "_") for a in ARCH_IDS}
 

@@ -6,9 +6,13 @@ for every assigned architecture, not just the paper's LLaMA-1B:
   eta_D(c)   — FLOPs of the device-side stage at cut layer c (Eq. 7 numerator)
   eta        — FLOPs of the whole fine-tuning step (Eq. 8)
   S(c), S~(c) — smashed data / gradient bytes (Eq. 9); identical across cuts
-                for uniform layer stacks (the paper's Fig. 3 observation)
+                (the paper's Fig. 3 observation)
   A(c)       — device-side LoRA adapter bytes (Eq. 9)
   D_{m,n}    — Eq. 10;  E_{m,n} — Eq. 11;  U — Eq. 12.
+
+Per-cut quantities sum the real layers in [0, c): a mixed stack (Granite
+4.0-H's Mamba and attention layers) prices each layer by its kind, and a
+uniform stack comes out as exactly ``c x`` one layer.
 
 FLOPs accounting: LoRA fine-tuning needs forward + backward-through-frozen
 weights (dX GEMMs) + adapter-gradient GEMMs, i.e. ~2x forward FLOPs + the
@@ -21,7 +25,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import cached_property
-from typing import NamedTuple, Optional, Sequence, Tuple
+from typing import Callable, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -52,16 +56,15 @@ def attn_fwd_flops_per_token(cfg: ModelConfig, seq_len: int) -> float:
 
 def mlp_fwd_flops_per_token(cfg: ModelConfig) -> float:
     """Forward FLOPs per token of one MLP block — gated 3-matmul for dense,
-    routed top-k + shared experts + router for MoE, 0.0 for pure SSM."""
+    routed top-k + shared experts + router for MoE, 0.0 where there is none
+    (d_ff 0, a pure SSM)."""
     d = cfg.d_model
     if cfg.is_moe:
         routed = 2 * 3 * d * cfg.d_ff * cfg.top_k
         shared = 2 * 3 * d * cfg.d_ff * cfg.n_shared_experts
         router = 2 * d * cfg.n_experts
         return routed + shared + router
-    if cfg.family == "ssm":
-        return 0.0
-    return 2 * 3 * d * cfg.d_ff
+    return float(2 * 3 * d * cfg.d_ff)
 
 
 def ssm_fwd_flops_per_token(cfg: ModelConfig) -> float:
@@ -86,6 +89,17 @@ def layer_fwd_flops_per_token(cfg: ModelConfig, seq_len: int) -> float:
             + mlp_fwd_flops_per_token(cfg)
             + ssm_fwd_flops_per_token(cfg)
             + lora_fwd_flops_per_token(cfg))
+
+
+def stack_sum(cfg: ModelConfig, lo: int, hi: int,
+              per_layer: Callable[[ModelConfig], float]) -> float:
+    """Sum of ``per_layer`` over layers [lo, hi), each given its kind's
+    uniform configuration: layers of a kind times one of them; a uniform
+    stack is ``(hi - lo) x`` one layer."""
+    if not cfg.layer_types:
+        return (hi - lo) * per_layer(cfg)
+    return sum(n * per_layer(cfg.kind_config(kind))
+               for kind, n in cfg.kind_counts(lo, hi).items())
 
 
 def embed_fwd_flops_per_token(cfg: ModelConfig) -> float:
@@ -117,17 +131,21 @@ class Workload:
         return self.batch * self.seq_len
 
     # ---- eta(c): Eq. 7/8 numerators ---------------------------------------
+    def _layers_flops(self, lo: int, hi: int) -> float:
+        return stack_sum(self.cfg, lo, hi, lambda c: layer_fwd_flops_per_token(
+            c, self.seq_len))
+
     def device_flops(self, cut: int) -> float:
         """eta_D(c): embedding + layers [0, cut), fwd+bwd, LoRA-frozen."""
         per_tok = (embed_fwd_flops_per_token(self.cfg)
-                   + cut * layer_fwd_flops_per_token(self.cfg, self.seq_len))
+                   + self._layers_flops(0, cut))
         return LORA_TRAIN_FACTOR * per_tok * self.tokens
 
     def total_flops(self) -> float:
         """eta: the whole model (device + server sides), fwd+bwd."""
         cfg = self.cfg
         per_tok = (embed_fwd_flops_per_token(cfg)
-                   + cfg.n_layers * layer_fwd_flops_per_token(cfg, self.seq_len)
+                   + self._layers_flops(0, cfg.n_layers)
                    + head_fwd_flops_per_token(cfg))
         return LORA_TRAIN_FACTOR * per_tok * self.tokens
 
@@ -136,8 +154,8 @@ class Workload:
 
     # ---- data sizes: Eq. 9 -------------------------------------------------
     def smashed_bytes(self, cut: int, act_bytes: int) -> float:
-        """S(c): activations at the cut + labels. Constant across cuts for a
-        uniform stack (matches the paper's observation)."""
+        """S(c): activations at the cut + labels. Constant across cuts
+        (matches the paper's observation)."""
         acts = self.tokens * self.cfg.d_model * act_bytes
         labels = self.tokens * 4
         return acts + labels
@@ -148,14 +166,15 @@ class Workload:
 
     def adapter_bytes(self, cut: int, adapter_bytes: int) -> float:
         """A(c): device-side LoRA adapters for layers [0, cut)."""
-        return cut * self.cfg.lora_params_per_layer() * adapter_bytes
+        return stack_sum(self.cfg, 0, cut,
+                         lambda c: c.lora_params_per_layer()) * adapter_bytes
 
     def device_weight_bytes(self, cut: int, weight_bytes: int = 2) -> float:
         """Frozen backbone bytes resident on the device at cut c (for the
         memory-feasibility mask; one-time download excluded from Eq. 9)."""
-        per_layer = self.cfg.params_per_layer() * weight_bytes
         embed = self.cfg.vocab_size * self.cfg.d_model * weight_bytes
-        return embed + cut * per_layer
+        return embed + stack_sum(self.cfg, 0, cut,
+                                 lambda c: c.params_per_layer() * weight_bytes)
 
 
 # ---------------------------------------------------------------------------

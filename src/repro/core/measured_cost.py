@@ -306,8 +306,9 @@ _ACT_BYTES = 4      # fp32 probe/compute activations
 
 
 def layer_hbm_bytes(cfg: ModelConfig, tokens: int) -> float:
-    """One decoder layer's forward HBM traffic: stream the (bf16) weights
-    once + read/write/residual the activation tensor."""
+    """One decoder layer's forward HBM traffic (``cfg`` of one layer kind):
+    stream the (bf16) weights once + read/write/residual the activation
+    tensor."""
     return (cfg.params_per_layer() * _WEIGHT_BYTES
             + 3.0 * tokens * cfg.d_model * _ACT_BYTES)
 
@@ -363,11 +364,13 @@ class LatencyTable:
         """Synthesize the table that reproduces the analytic model exactly:
         ref_throughput 1.0, 'seconds' = forward FLOPs of each component."""
         cfg, tok = workload.cfg, workload.tokens
-        layer = layer_fwd_flops_per_token(cfg, workload.seq_len) * tok
+        layer = {k: layer_fwd_flops_per_token(cfg.kind_config(k),
+                                              workload.seq_len) * tok
+                 for k in set(cfg.layer_kinds)}
         return cls(arch=cfg.name, batch=workload.batch,
                    seq_len=workload.seq_len, ref_throughput=1.0,
                    embed_s=embed_fwd_flops_per_token(cfg) * tok,
-                   layer_s=(layer,) * cfg.n_layers,
+                   layer_s=tuple(layer[k] for k in cfg.layer_kinds),
                    head_s=head_fwd_flops_per_token(cfg) * tok,
                    source="analytic")
 
@@ -377,13 +380,15 @@ class LatencyTable:
         """Predict per-layer latency from the fitted roofline: compute term
         (analytic FLOPs / C) + bandwidth term (HBM footprint / B)."""
         tok = batch * seq_len
-        layer = fit.predict(layer_fwd_flops_per_token(cfg, seq_len) * tok,
-                            layer_hbm_bytes(cfg, tok))
+        layer = {k: fit.predict(
+                     layer_fwd_flops_per_token(cfg.kind_config(k), seq_len)
+                     * tok, layer_hbm_bytes(cfg.kind_config(k), tok))
+                 for k in set(cfg.layer_kinds)}
         return cls(arch=cfg.name, batch=batch, seq_len=seq_len,
                    ref_throughput=fit.ref_throughput,
                    embed_s=fit.predict(embed_fwd_flops_per_token(cfg) * tok,
                                        embed_hbm_bytes(cfg, tok)),
-                   layer_s=(layer,) * cfg.n_layers,
+                   layer_s=tuple(layer[k] for k in cfg.layer_kinds),
                    head_s=fit.predict(head_fwd_flops_per_token(cfg) * tok,
                                       head_hbm_bytes(cfg, tok)),
                    source=f"measured:{fit.backend}")

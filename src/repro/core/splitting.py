@@ -30,7 +30,7 @@ gaps down to a stage.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -97,8 +97,15 @@ def channel_compress(x: jax.Array, enabled: bool) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def split_lora(lora: Params, cut: int) -> Tuple[Params, Params]:
-    """R = {R^D ; R^S} (Eq. 6, inverse): split adapters at the cut."""
+def split_lora(lora: Params, cut: int, cfg: Optional[ModelConfig] = None
+               ) -> Tuple[Params, Params]:
+    """R = {R^D ; R^S} (Eq. 6, inverse): split adapters at the cut. A
+    mixed stack (``cfg.layer_types``) splits each kind's stack at the rows
+    of the layers below the cut."""
+    if cfg is not None and cfg.layer_types:
+        return ({"layers": model_lib.slice_stack(cfg, lora["layers"], 0, cut)},
+                {"layers": model_lib.slice_stack(cfg, lora["layers"], cut,
+                                                 cfg.n_layers)})
     dev = {"layers": model_lib.slice_layers(lora["layers"], 0, cut)}
     n = jax.tree_util.tree_leaves(lora["layers"])[0].shape[0]
     srv = {"layers": model_lib.slice_layers(lora["layers"], cut, n)}
@@ -106,7 +113,8 @@ def split_lora(lora: Params, cut: int) -> Tuple[Params, Params]:
 
 
 def merge_lora(dev: Params, srv: Params) -> Params:
-    """Stage 5, Eq. 6: R = {R^{D,T} ; R^{S,T}}."""
+    """Stage 5, Eq. 6: R = {R^{D,T} ; R^{S,T}}; each leaf's rows, per kind
+    in a mixed stack, are the device's then the server's."""
     merged = jax.tree_util.tree_map(
         lambda a, b: jnp.concatenate([a, b], axis=0),
         dev["layers"], srv["layers"])
@@ -194,7 +202,7 @@ class SplitExecutor:
                              ) -> Tuple[jax.Array, Params]:
             """The adapters split at the cut, ``split_grads`` (inlined), the
             gradients merged back."""
-            lora_dev, lora_srv = split_lora(lora, cut)
+            lora_dev, lora_srv = split_lora(lora, cut, cfg)
             loss, g_dev, g_srv = split_grads(
                 frozen, lora_dev, lora_srv, inputs, labels, cfg=cfg, cut=cut,
                 impl=impl, compress=compress)

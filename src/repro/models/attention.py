@@ -8,8 +8,9 @@ Three score paths:
   * ``pallas``  — the TPU Pallas kernel in ``repro.kernels.flash_attention``
                   (validated in interpret mode on CPU).
 
-Supports causal masking, sliding windows (SWA), GQA head grouping, RoPE,
-qk-norm (Qwen3) and QKV bias (Qwen2).
+Supports causal masking, sliding windows (SWA), GQA head grouping, RoPE or
+no positions (NoPE, Granite 4.0-H), a published softmax scale in place of
+1/sqrt(head_dim), qk-norm (Qwen3) and QKV bias (Qwen2).
 """
 from __future__ import annotations
 
@@ -79,14 +80,18 @@ def _split_heads(x: jax.Array, n_heads: int) -> jax.Array:
 
 
 def naive_attention(q, k, v, *, causal: bool, window: int,
-                    q_positions, k_positions) -> jax.Array:
-    """q: (B,Sq,Hq,D); k,v: (B,Skv,Hkv,D). Oracle path."""
+                    q_positions, k_positions,
+                    scale: Optional[float] = None) -> jax.Array:
+    """q: (B,Sq,Hq,D); k,v: (B,Skv,Hkv,D). Oracle path. ``scale``: the
+    softmax scale, 1/sqrt(D) if None."""
     b, sq, hq, d = q.shape
     hkv = k.shape[2]
     group = hq // hkv
     qg = q.reshape(b, sq, hkv, group, d)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(ACC_DTYPE),
-                        k.astype(ACC_DTYPE)) / jnp.sqrt(float(d))
+                        k.astype(ACC_DTYPE))
+    scores = (scores / jnp.sqrt(float(d)) if scale is None
+              else scores * scale)
     mask = k_positions[:, None, :] <= q_positions[:, :, None]  # (B,Sq,Skv)
     if not causal:
         mask = jnp.ones_like(mask)
@@ -99,7 +104,7 @@ def naive_attention(q, k, v, *, causal: bool, window: int,
 
 
 def chunked_attention(q, k, v, *, causal: bool, window: int,
-                      q_positions, k_positions,
+                      q_positions, k_positions, scale: Optional[float] = None,
                       block_q: int = 512, block_k: int = 1024) -> jax.Array:
     """Flash-style online softmax, pure jnp. Same signature as naive."""
     b, sq, hq, d = q.shape
@@ -107,7 +112,8 @@ def chunked_attention(q, k, v, *, causal: bool, window: int,
     group = hq // hkv
     if sq <= block_q and skv <= block_k:
         return naive_attention(q, k, v, causal=causal, window=window,
-                               q_positions=q_positions, k_positions=k_positions)
+                               q_positions=q_positions, k_positions=k_positions,
+                               scale=scale)
 
     pad_q = (-sq) % block_q
     pad_k = (-skv) % block_k
@@ -127,7 +133,8 @@ def chunked_attention(q, k, v, *, causal: bool, window: int,
     vb = vp.reshape(b, nk, block_k, hkv, d)
     qposb = qpos.reshape(b, nq, block_q)
     kposb = kpos.reshape(b, nk, block_k)
-    scale = 1.0 / jnp.sqrt(float(d))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(float(d))
 
     def one_q_block(args):
         qi, qpos_i = args  # (b, block_q, hkv, g, d), (b, block_q)
@@ -169,15 +176,34 @@ def chunked_attention(q, k, v, *, causal: bool, window: int,
     return out[:, :sq].astype(q.dtype)
 
 
+def softmax_scale(cfg: ModelConfig) -> Optional[float]:
+    """The published softmax scale, or None for 1/sqrt(head_dim)."""
+    return cfg.attention_multiplier or None
+
+
+def positioned(x: jax.Array, positions: jax.Array, cfg: ModelConfig
+               ) -> jax.Array:
+    """RoPE at ``positions``; NoPE configurations take none."""
+    if cfg.position_embedding == "nope":
+        return x
+    return apply_rope(x, positions, cfg.rope_theta)
+
+
 def attention_scores(q, k, v, *, impl: str, causal: bool, window: int,
-                     q_positions, k_positions) -> jax.Array:
+                     q_positions, k_positions,
+                     scale: Optional[float] = None) -> jax.Array:
     if impl == "naive":
         return naive_attention(q, k, v, causal=causal, window=window,
-                               q_positions=q_positions, k_positions=k_positions)
+                               q_positions=q_positions, k_positions=k_positions,
+                               scale=scale)
     if impl == "chunked":
         return chunked_attention(q, k, v, causal=causal, window=window,
-                                 q_positions=q_positions, k_positions=k_positions)
+                                 q_positions=q_positions, k_positions=k_positions,
+                                 scale=scale)
     if impl == "pallas":
+        if scale is not None:
+            raise ValueError("the Pallas attention kernel scales by "
+                             "1/sqrt(head_dim) only")
         from repro.kernels import ops as kernel_ops
         return kernel_ops.flash_attention(q, k, v, causal=causal, window=window,
                                           q_positions=q_positions,
@@ -214,11 +240,12 @@ def attention_forward(params: Params, lora: Optional[Params], x: jax.Array,
     if cfg.qk_norm:
         q = rms_norm(q, params["q_norm"], cfg.rms_eps)
         k = rms_norm(k, params["k_norm"], cfg.rms_eps)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = positioned(q, positions, cfg)
+    k = positioned(k, positions, cfg)
     out = attention_scores(q, k, v, impl=impl, causal=True,
                            window=cfg.sliding_window,
-                           q_positions=positions, k_positions=positions)
+                           q_positions=positions, k_positions=positions,
+                           scale=softmax_scale(cfg))
     out = out.reshape(x.shape[0], x.shape[1], cfg.q_dim)
     out = lora_dense(out, params["wo"], maybe_lora(lora, "wo"), scale,
                      use_kernel=use_lora_kernel)
@@ -326,8 +353,8 @@ def attention_decode(params: Params, lora: Optional[Params], x: jax.Array,
     if cfg.qk_norm:
         q = rms_norm(q, params["q_norm"], cfg.rms_eps)
         k = rms_norm(k, params["k_norm"], cfg.rms_eps)
-    q = apply_rope(q, pos, cfg.rope_theta)
-    k = apply_rope(k, pos, cfg.rope_theta)
+    q = positioned(q, pos, cfg)
+    k = positioned(k, pos, cfg)
 
     slots = cache["k"].shape[1]
     slot = (t % slots).astype(jnp.int32)
@@ -337,7 +364,8 @@ def attention_decode(params: Params, lora: Optional[Params], x: jax.Array,
 
     out = naive_attention(q, k_cache, v_cache, causal=True,
                           window=cfg.sliding_window,
-                          q_positions=pos, k_positions=k_positions)
+                          q_positions=pos, k_positions=k_positions,
+                          scale=softmax_scale(cfg))
     out = out.reshape(b, 1, cfg.q_dim)
     out = lora_dense(out, params["wo"], maybe_lora(lora, "wo"), scale,
                      None, uk)
@@ -374,8 +402,8 @@ def attention_prefill(params: Params, lora: Optional[Params], x: jax.Array,
     if cfg.qk_norm:
         q = rms_norm(q, params["q_norm"], cfg.rms_eps)
         k = rms_norm(k, params["k_norm"], cfg.rms_eps)
-    q = apply_rope(q, pos, cfg.rope_theta)
-    k = apply_rope(k, pos, cfg.rope_theta)
+    q = positioned(q, pos, cfg)
+    k = positioned(k, pos, cfg)
 
     slots = cache["k"].shape[1]
     idx = (positions % slots).astype(jnp.int32)            # (C,)
@@ -400,7 +428,8 @@ def attention_prefill(params: Params, lora: Optional[Params], x: jax.Array,
                                        cfg.sliding_window, b)
     out = naive_attention(q, k_cache, v_cache, causal=True,
                           window=cfg.sliding_window,
-                          q_positions=pos, k_positions=k_positions)
+                          q_positions=pos, k_positions=k_positions,
+                          scale=softmax_scale(cfg))
     out = out.reshape(b, c, cfg.q_dim)
     out = lora_dense(out, params["wo"], maybe_lora(lora, "wo"), scale,
                      None, uk)

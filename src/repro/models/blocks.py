@@ -1,4 +1,8 @@
-"""Per-family layer blocks (pre-norm residual), stacked for lax.scan."""
+"""Per-family layer blocks (pre-norm residual), stacked for lax.scan.
+
+An ``ssm`` layer with ``d_ff > 0`` (a mixed stack's Mamba layer) follows
+its mixer with the MLP, as an attention layer does. Every block output is
+scaled by ``residual_multiplier`` before it joins the residual stream."""
 from __future__ import annotations
 
 from typing import Optional, Tuple
@@ -16,11 +20,20 @@ from repro.models.common import Params, init_rms_norm, rms_norm
 ATTN_FAMILIES = ("dense", "moe", "hybrid", "audio", "vlm")
 
 
+def _residual(x: jax.Array, out: jax.Array, cfg: ModelConfig) -> jax.Array:
+    if cfg.residual_multiplier == 1.0:
+        return x + out
+    return x + out * cfg.residual_multiplier
+
+
 def init_layer(key, cfg: ModelConfig, dtype) -> Params:
     ks = jax.random.split(key, 3)
     p: Params = {"norm1": init_rms_norm(cfg.d_model)}
     if cfg.family == "ssm":
         p["mamba"] = mamba_mod.init_mamba(ks[0], cfg, dtype)
+        if cfg.d_ff:
+            p["norm2"] = init_rms_norm(cfg.d_model)
+            p["mlp"] = mlp_mod.init_mlp(ks[1], cfg, dtype)
         return p
     p["attn"] = attn_mod.init_attention(ks[0], cfg, dtype)
     p["norm2"] = init_rms_norm(cfg.d_model)
@@ -38,6 +51,8 @@ def init_layer_lora(key, cfg: ModelConfig) -> Params:
     p: Params = {}
     if cfg.family == "ssm":
         p["mamba"] = mamba_mod.init_mamba_lora(ks[0], cfg)
+        if cfg.d_ff:
+            p["mlp"] = mlp_mod.init_mlp_lora(ks[1], cfg)
         return p
     p["attn"] = attn_mod.init_attention_lora(ks[0], cfg)
     if cfg.family == "moe":
@@ -58,26 +73,28 @@ def layer_forward(params: Params, lora: Optional[Params], x: jax.Array,
     lget = (lambda k: lora.get(k) if lora is not None else None)
     h = rms_norm(x, params["norm1"], cfg.rms_eps)
     if cfg.family == "ssm":
-        x = x + mamba_mod.mamba_forward(params["mamba"], lget("mamba"), h, cfg,
-                                        use_lora_kernel)
-        return x, aux
-    attn_out, _ = attn_mod.attention_forward(
-        params["attn"], lget("attn"), h, cfg, positions=positions, impl=impl,
-        use_lora_kernel=use_lora_kernel)
-    if cfg.family == "hybrid":
-        ssm_out = mamba_mod.mamba_forward(params["mamba"], lget("mamba"), h,
-                                          cfg, use_lora_kernel)
-        x = x + 0.5 * (attn_out + ssm_out)
+        x = _residual(x, mamba_mod.mamba_forward(
+            params["mamba"], lget("mamba"), h, cfg, use_lora_kernel), cfg)
+        if not cfg.d_ff:
+            return x, aux
     else:
-        x = x + attn_out
+        attn_out, _ = attn_mod.attention_forward(
+            params["attn"], lget("attn"), h, cfg, positions=positions,
+            impl=impl, use_lora_kernel=use_lora_kernel)
+        if cfg.family == "hybrid":
+            ssm_out = mamba_mod.mamba_forward(params["mamba"], lget("mamba"),
+                                              h, cfg, use_lora_kernel)
+            x = x + 0.5 * (attn_out + ssm_out)
+        else:
+            x = _residual(x, attn_out, cfg)
     h2 = rms_norm(x, params["norm2"], cfg.rms_eps)
     if cfg.family == "moe":
         moe_out, aux = _moe_dispatch(params["moe"], lget("moe"), h2, cfg,
                                      use_lora_kernel)
         x = x + moe_out
     else:
-        x = x + mlp_mod.mlp_forward(params["mlp"], lget("mlp"), h2, cfg,
-                                    use_lora_kernel)
+        x = _residual(x, mlp_mod.mlp_forward(params["mlp"], lget("mlp"), h2,
+                                             cfg, use_lora_kernel), cfg)
     return x, aux
 
 
@@ -116,15 +133,15 @@ def layer_prefill(params: Params, lora: Optional[Params], x: jax.Array,
     attn_out, new_cache["kv"] = attn_mod.attention_prefill(
         params["attn"], lget("attn"), h, cache["kv"], cfg, positions=positions,
         use_lora_kernel=use_lora_kernel)
-    x = x + attn_out
+    x = _residual(x, attn_out, cfg)
     h2 = rms_norm(x, params["norm2"], cfg.rms_eps)
     if cfg.family == "moe":
         moe_out, _ = _moe_dispatch(params["moe"], lget("moe"), h2, cfg,
                                    use_lora_kernel)
         x = x + moe_out
     else:
-        x = x + mlp_mod.mlp_forward(params["mlp"], lget("mlp"), h2, cfg,
-                                    use_lora_kernel)
+        x = _residual(x, mlp_mod.mlp_forward(params["mlp"], lget("mlp"), h2,
+                                             cfg, use_lora_kernel), cfg)
     return x, new_cache
 
 
@@ -138,22 +155,25 @@ def layer_decode(params: Params, lora: Optional[Params], x: jax.Array,
     if cfg.family == "ssm":
         out, new_cache["ssm"] = mamba_mod.mamba_decode(
             params["mamba"], lget("mamba"), h, cache["ssm"], cfg)
-        return x + out, new_cache
-    attn_out, new_cache["kv"] = attn_mod.attention_decode(
-        params["attn"], lget("attn"), h, cache["kv"], cfg, t=t,
-        use_lora_kernel=use_lora_kernel)
-    if cfg.family == "hybrid":
-        ssm_out, new_cache["ssm"] = mamba_mod.mamba_decode(
-            params["mamba"], lget("mamba"), h, cache["ssm"], cfg)
-        x = x + 0.5 * (attn_out + ssm_out)
+        x = _residual(x, out, cfg)
+        if not cfg.d_ff:
+            return x, new_cache
     else:
-        x = x + attn_out
+        attn_out, new_cache["kv"] = attn_mod.attention_decode(
+            params["attn"], lget("attn"), h, cache["kv"], cfg, t=t,
+            use_lora_kernel=use_lora_kernel)
+        if cfg.family == "hybrid":
+            ssm_out, new_cache["ssm"] = mamba_mod.mamba_decode(
+                params["mamba"], lget("mamba"), h, cache["ssm"], cfg)
+            x = x + 0.5 * (attn_out + ssm_out)
+        else:
+            x = _residual(x, attn_out, cfg)
     h2 = rms_norm(x, params["norm2"], cfg.rms_eps)
     if cfg.family == "moe":
         moe_out, _ = _moe_dispatch(params["moe"], lget("moe"), h2, cfg,
                                    use_lora_kernel)
         x = x + moe_out
     else:
-        x = x + mlp_mod.mlp_forward(params["mlp"], lget("mlp"), h2, cfg,
-                                    use_lora_kernel)
+        x = _residual(x, mlp_mod.mlp_forward(params["mlp"], lget("mlp"), h2,
+                                             cfg, use_lora_kernel), cfg)
     return x, new_cache

@@ -5,6 +5,10 @@ Single B/C group (ngroups=1), head structure (nh heads x hp head_dim).
 The chunked SSD math here is the pure-jnp oracle shared with
 ``repro.kernels.ssd_scan``; the Pallas kernel implements the intra-chunk
 part with VMEM tiling.
+
+The full-sequence mixer runs under the named scope ``ssm.mixer`` and its
+SSD scan under ``ssm.scan``; both reach the compiled program's op
+metadata, the backward pass under ``transpose(...)``.
 """
 from __future__ import annotations
 
@@ -18,6 +22,9 @@ from repro.configs.base import ModelConfig
 from repro.models.common import (ACC_DTYPE, Params, dense_init,
                                  init_lora_pair, init_rms_norm, lora_dense,
                                  maybe_lora, rms_norm, silu)
+
+SCOPE_SSM_MIXER = "ssm.mixer"   # in_proj through out_proj
+SCOPE_SSM_SCAN = "ssm.scan"     # the SSD scan inside it
 
 
 def init_mamba(key, cfg: ModelConfig, dtype) -> Params:
@@ -68,7 +75,12 @@ def ssd_chunked(xt: jax.Array, a: jax.Array, B: jax.Array, C: jax.Array,
                 chunk: int, h0: Optional[jax.Array] = None
                 ) -> Tuple[jax.Array, jax.Array]:
     """SSD scan. xt: (B,L,nh,hp) pre-multiplied by dt; a: (B,L,nh) = A*dt
-    (<=0); B,C: (B,L,ns). Returns (y: (B,L,nh,hp), h_final: (B,nh,hp,ns))."""
+    (<=0); B,C: (B,L,ns). Returns (y: (B,L,nh,hp), h_final: (B,nh,hp,ns)).
+
+    Every contraction is pairwise, so no tensor holds a state (hp x ns) per
+    position or an (i, j) pair per head dimension: the largest terms are
+    the intra-chunk weights (b, nc, chunk, chunk, nh) and one state per
+    chunk (b, nc, nh, hp, ns)."""
     b, l, nh, hp = xt.shape
     ns = B.shape[-1]
     pad = (-l) % chunk
@@ -84,17 +96,20 @@ def ssd_chunked(xt: jax.Array, a: jax.Array, B: jax.Array, C: jax.Array,
     Cc = C.reshape(b, nc, chunk, ns).astype(ACC_DTYPE)
 
     cum = jnp.cumsum(a, axis=2)                          # (b,nc,cl,nh)
-    # intra-chunk (quadratic within chunk)
+    # intra-chunk: y_i = sum_{j<=i} (C_i.B_j) exp(cum_i - cum_j) x_j; the
+    # mask goes in before exp, so no masked entry overflows (nor its
+    # gradient)
     seg = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # (b,nc,i,j,nh)
     causal = jnp.tril(jnp.ones((chunk, chunk), bool))
-    decay = jnp.where(causal[None, None, :, :, None], jnp.exp(seg), 0.0)
+    decay = jnp.exp(jnp.where(causal[None, None, :, :, None], seg, -jnp.inf))
     scores = jnp.einsum("bcin,bcjn->bcij", Cc, Bc)       # (b,nc,i,j)
-    y_diag = jnp.einsum("bcij,bcijh,bcjhp->bcihp",
-                        scores, decay, xt)
+    weights = scores[..., None] * decay                  # (b,nc,i,j,nh)
+    y_diag = jnp.einsum("bcijh,bcjhp->bcihp", weights, xt)
 
-    # chunk-final states
+    # chunk-final states: x weighted by its decay to the chunk's end, then
+    # contracted with B over the chunk's positions
     dec_end = jnp.exp(cum[:, :, -1:, :] - cum)           # (b,nc,cl,nh)
-    states = jnp.einsum("bcjn,bcjh,bcjhp->bchpn", Bc, dec_end, xt)
+    states = jnp.einsum("bcjn,bcjhp->bchpn", Bc, xt * dec_end[..., None])
 
     # inter-chunk recurrence
     a_tot = jnp.exp(cum[:, :, -1, :])                    # (b,nc,nh)
@@ -112,9 +127,9 @@ def ssd_chunked(xt: jax.Array, a: jax.Array, B: jax.Array, C: jax.Array,
         step, h0, (a_tot.transpose(1, 0, 2), states.transpose(1, 0, 2, 3, 4)))
     h_prevs = h_prevs.transpose(1, 0, 2, 3, 4)           # (b,nc,nh,hp,ns)
 
-    y_off = jnp.einsum("bcin,bcihpn->bcihp",
-                       Cc, jnp.exp(cum)[..., None, None]
-                       * h_prevs[:, :, None], )
+    # the state entering each chunk, read by C, decayed to each position
+    y_off = (jnp.einsum("bcin,bchpn->bcihp", Cc, h_prevs)
+             * jnp.exp(cum)[..., None])
     y = (y_diag + y_off).reshape(b, nc * chunk, nh, hp)
     return y[:, :l], h_final
 
@@ -123,27 +138,30 @@ def mamba_forward(params: Params, lora: Optional[Params], x: jax.Array,
                   cfg: ModelConfig, use_lora_kernel: bool = False
                   ) -> jax.Array:
     """Full-sequence forward. x: (B,L,d) -> (B,L,d)."""
-    di, ns, nh = cfg.ssm_d_inner, cfg.ssm_state, cfg.ssm_n_heads
-    hp = cfg.ssm_head_dim
-    proj = lora_dense(x, params["in_proj"], maybe_lora(lora, "in_proj"),
-                      cfg.lora.scale, use_kernel=use_lora_kernel)
-    z, xs, B, C, dt_raw = jnp.split(
-        proj, [di, 2 * di, 2 * di + ns, 2 * di + 2 * ns], axis=-1)
-    xbc = _causal_conv(jnp.concatenate([xs, B, C], -1),
-                       params["conv_w"], params["conv_b"])
-    xs, B, C = jnp.split(xbc, [di, di + ns], axis=-1)
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + params["dt_bias"])
-    A = -jnp.exp(params["a_log"])                        # (nh,)
-    bsz, l = x.shape[0], x.shape[1]
-    xh = xs.reshape(bsz, l, nh, hp)
-    xt = xh.astype(ACC_DTYPE) * dt[..., None]
-    a = dt * A
-    y, _ = ssd_chunked(xt, a, B, C, cfg.ssm_chunk)
-    y = y + params["d_skip"][:, None] * xh.astype(ACC_DTYPE)
-    y = y.reshape(bsz, l, di).astype(x.dtype)
-    y = rms_norm(y * silu(z), params["gate_norm"], cfg.rms_eps)
-    return lora_dense(y, params["out_proj"], maybe_lora(lora, "out_proj"),
-                      cfg.lora.scale, use_kernel=use_lora_kernel)
+    with jax.named_scope(SCOPE_SSM_MIXER):
+        di, ns, nh = cfg.ssm_d_inner, cfg.ssm_state, cfg.ssm_n_heads
+        hp = cfg.ssm_head_dim
+        proj = lora_dense(x, params["in_proj"], maybe_lora(lora, "in_proj"),
+                          cfg.lora.scale, use_kernel=use_lora_kernel)
+        z, xs, B, C, dt_raw = jnp.split(
+            proj, [di, 2 * di, 2 * di + ns, 2 * di + 2 * ns], axis=-1)
+        xbc = _causal_conv(jnp.concatenate([xs, B, C], -1),
+                           params["conv_w"], params["conv_b"])
+        xs, B, C = jnp.split(xbc, [di, di + ns], axis=-1)
+        dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + params["dt_bias"])
+        A = -jnp.exp(params["a_log"])                    # (nh,)
+        bsz, l = x.shape[0], x.shape[1]
+        xh = xs.reshape(bsz, l, nh, hp)
+        xt = xh.astype(ACC_DTYPE) * dt[..., None]
+        a = dt * A
+        with jax.named_scope(SCOPE_SSM_SCAN):
+            y, _ = ssd_chunked(xt, a, B, C, cfg.ssm_chunk)
+        y = y + params["d_skip"][:, None] * xh.astype(ACC_DTYPE)
+        y = y.reshape(bsz, l, di).astype(x.dtype)
+        y = rms_norm(y * silu(z), params["gate_norm"], cfg.rms_eps)
+        return lora_dense(y, params["out_proj"],
+                          maybe_lora(lora, "out_proj"), cfg.lora.scale,
+                          use_kernel=use_lora_kernel)
 
 
 # ---------------------------------------------------------------------------

@@ -76,7 +76,8 @@ def test_decode_step(arch):
 
 
 @pytest.mark.parametrize("arch", ["qwen3-0.6b", "mamba2-370m", "hymba-1.5b",
-                                  "granite-moe-3b-a800m", "musicgen-large"])
+                                  "granite-moe-3b-a800m", "musicgen-large",
+                                  "granite-4.0-h-micro"])
 def test_decode_matches_forward(arch):
     """Step-by-step decode must reproduce the full-sequence forward."""
     cfg = get_config(arch).reduced()

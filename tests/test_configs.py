@@ -1,4 +1,6 @@
 """Config registry: every assigned arch resolves, with the exact shapes."""
+import dataclasses
+
 import pytest
 
 from repro.configs.base import (ARCH_IDS, INPUT_SHAPES, all_configs,
@@ -16,6 +18,7 @@ EXPECTED = {
     "hymba-1.5b": (32, 1600, 25, 5, 5504, 32001),
     "internvl2-26b": (48, 6144, 48, 8, 16384, 92553),
     "qwen2-7b": (28, 3584, 28, 4, 18944, 152064),
+    "granite-4.0-h-micro": (40, 2048, 32, 8, 8192, 100352),
 }
 
 
@@ -43,6 +46,21 @@ def test_ssm_fields():
     assert h.ssm_state == 16 and h.family == "hybrid"
 
 
+def test_mixed_stack_fields():
+    g = get_config("granite-4.0-h-micro")
+    assert g.family == "mixed" and g.has_ssm and not g.is_attention_free
+    assert g.kind_counts(0, g.n_layers) == {"mamba": 36, "attention": 4}
+    assert g.kind_config("mamba").family == "ssm"
+    assert g.kind_config("attention").family == "dense"
+    assert (g.kind_index("mamba", 6), g.kind_index("attention", 6)) == (5, 1)
+    assert (g.position_embedding, g.attention_multiplier) == ("nope", 1 / 64)
+    assert abs(g.total_params() - 3.19e9) / 3.19e9 < 0.01
+    with pytest.raises(ValueError):
+        g.params_per_layer()
+    with pytest.raises(ValueError):
+        dataclasses.replace(g, n_layers=39)
+
+
 def test_reduced_variants_are_small():
     for arch, cfg in all_configs().items():
         r = cfg.reduced()
@@ -67,4 +85,5 @@ def test_input_shapes():
     assert INPUT_SHAPES["prefill_32k"].global_batch == 32
     assert INPUT_SHAPES["decode_32k"].global_batch == 128
     assert INPUT_SHAPES["long_500k"].seq_len == 524288
-    assert len(ARCH_IDS) == 11  # 10 assigned + the paper's llama32-1b
+    # 10 assigned + the paper's llama32-1b + granite-4.0-h-micro
+    assert len(ARCH_IDS) == 12

@@ -118,3 +118,96 @@ def test_memory_feasibility_mask():
                         server=SERVER_RTX4060TI,
                         channel=ChannelState(25, 30, 20e6), sim=DEFAULT_SIM)
     assert ctx2.max_feasible_cut() == w2.cfg.n_layers
+
+
+# ---- per-layer pricing of mixed stacks -----------------------------------
+
+GRANITE = get_config("granite-4.0-h-micro")
+
+
+@pytest.mark.parametrize("cut", [0, 1, 5, 6, 16, 39, 40])
+def test_mixed_stack_prices_the_layers_below_the_cut(cut):
+    """Granite 4.0-H: each per-cut quantity is the sum over its real layers
+    [0, cut), Mamba and attention priced apart."""
+    from repro.core.cost_model import (embed_fwd_flops_per_token,
+                                       layer_fwd_flops_per_token)
+    w = Workload(GRANITE, 4, 512)
+    kinds = GRANITE.layer_types[:cut]
+    one = {k: GRANITE.kind_config(k) for k in ("mamba", "attention")}
+    flops = sum(layer_fwd_flops_per_token(one[k], 512) for k in kinds)
+    assert w.device_flops(cut) == pytest.approx(
+        2.0 * (embed_fwd_flops_per_token(GRANITE) + flops) * w.tokens,
+        rel=1e-12)
+    assert w.device_weight_bytes(cut) == \
+        GRANITE.vocab_size * GRANITE.d_model * 2 \
+        + sum(one[k].params_per_layer() * 2 for k in kinds)
+    assert w.adapter_bytes(cut, 4) == \
+        4 * sum(one[k].lora_params_per_layer() for k in kinds)
+    assert one["mamba"].params_per_layer() != \
+        one["attention"].params_per_layer()
+
+
+def test_mixed_stack_layer_costs_differ_by_kind():
+    w = Workload(GRANITE, 4, 512)
+    inc = [w.device_flops(c + 1) - w.device_flops(c)
+           for c in range(GRANITE.n_layers)]
+    kinds = GRANITE.layer_types
+    assert len({round(v) for v, k in zip(inc, kinds) if k == "mamba"}) == 1
+    assert len({round(v) for v, k in zip(inc, kinds) if k == "attention"}) \
+        == 1
+    assert inc[5] < inc[4]          # attention is the cheaper kind here
+    assert GRANITE.total_params() == pytest.approx(3.19e9, rel=1e-2)
+
+
+class _OneLayerTimesCut(Workload):
+    """The pricing before mixed stacks: cut x one layer."""
+
+    def device_flops(self, cut):
+        from repro.core.cost_model import (embed_fwd_flops_per_token,
+                                           layer_fwd_flops_per_token)
+        return 2.0 * (embed_fwd_flops_per_token(self.cfg) + cut
+                      * layer_fwd_flops_per_token(self.cfg, self.seq_len)
+                      ) * self.tokens
+
+    def total_flops(self):
+        from repro.core.cost_model import (embed_fwd_flops_per_token,
+                                           head_fwd_flops_per_token,
+                                           layer_fwd_flops_per_token)
+        cfg = self.cfg
+        return 2.0 * (embed_fwd_flops_per_token(cfg) + cfg.n_layers
+                      * layer_fwd_flops_per_token(cfg, self.seq_len)
+                      + head_fwd_flops_per_token(cfg)) * self.tokens
+
+    def adapter_bytes(self, cut, adapter_bytes):
+        return cut * self.cfg.lora_params_per_layer() * adapter_bytes
+
+    def device_weight_bytes(self, cut, weight_bytes=2):
+        return (self.cfg.vocab_size * self.cfg.d_model * weight_bytes
+                + cut * self.cfg.params_per_layer() * weight_bytes)
+
+
+@pytest.mark.parametrize("seed", [11, 2147600101, 77])
+def test_uniform_stack_card_decisions_unchanged(seed):
+    """qwen3-0.6b under the benchmark's Table II contexts (5 Table I
+    devices, "normal" channel, 4 x 512 tokens, 5 local epochs, 40 rounds):
+    per-layer pricing decides exactly as cut x one layer did."""
+    from repro.core import card
+    from repro.core.channel import SEED_STRIDE, WirelessChannel
+    cfg = get_config("qwen3-0.6b")
+    sim = SimParams(mini_batch=4, seq_len=512, local_epochs=5)
+    new, old = Workload(cfg, 4, 512), _OneLayerTimesCut(cfg, 4, 512)
+    for c in range(cfg.n_layers + 1):
+        assert new.device_flops(c) == old.device_flops(c)
+        assert new.adapter_bytes(c, 4) == old.adapter_bytes(c, 4)
+        assert new.device_weight_bytes(c) == old.device_weight_bytes(c)
+    assert new.total_flops() == old.total_flops()
+    chans = [WirelessChannel("normal", seed=seed + SEED_STRIDE * m)
+             for m in range(5)]
+    for _ in range(40):
+        for m, ch in enumerate(chans):
+            state = ch.draw()
+            got, want = (card.card(RoundContext(
+                workload=w, device=EDGE_FLEET[m], server=SERVER_RTX4060TI,
+                channel=state, sim=sim)) for w in (new, old))
+            assert (got.cut, got.frequency, got.cost) == \
+                (want.cut, want.frequency, want.cost)

@@ -49,6 +49,29 @@ def test_ssd_equals_naive_recurrence():
     np.testing.assert_allclose(np.asarray(h_final), h, atol=2e-5)
 
 
+def test_ssd_from_a_state_equals_recurrence():
+    """From an initial state h0, at a length no multiple of the chunk: the
+    outputs and the final state are the literal recurrence's."""
+    b, l, nh, hp, ns, chunk = 2, 37, 3, 4, 5, 8
+    xt = _rand(10, b, l, nh, hp)
+    a = -jnp.abs(_rand(11, b, l, nh, scale=0.3))
+    B = _rand(12, b, l, ns)
+    C = _rand(13, b, l, ns)
+    h0 = _rand(14, b, nh, hp, ns)
+    y, h_final = ssd_chunked(xt, a, B, C, chunk, h0=h0)
+
+    h = np.asarray(h0, np.float64)
+    ys = []
+    for t in range(l):
+        h = h * np.exp(np.asarray(a)[:, t])[:, :, None, None] \
+            + np.asarray(xt)[:, t][:, :, :, None] \
+            * np.asarray(B)[:, t][:, None, None, :]
+        ys.append(np.einsum("bhpn,bn->bhp", h, np.asarray(C)[:, t]))
+    np.testing.assert_allclose(np.asarray(y), np.stack(ys, axis=1),
+                               atol=2e-5)
+    np.testing.assert_allclose(np.asarray(h_final), h, atol=2e-5)
+
+
 def test_zero_decay_is_cumulative_sum():
     """a == 0 (no decay): the state is a running sum of B-weighted inputs."""
     b, l, nh, hp, ns = 1, 24, 1, 2, 3

@@ -21,6 +21,7 @@ from repro.core.splitting import (SCOPE_DEVICE_STAGE, SCOPE_HEAD,
                                   SPAN_OPTIMIZER, SPAN_ROUND, SPANS,
                                   split_grads, split_lora)
 from repro.models import model as M
+from repro.models.mamba import SCOPE_SSM_MIXER, SCOPE_SSM_SCAN
 from repro.optim import adamw, constant_schedule
 
 
@@ -128,3 +129,36 @@ def test_spans_nest_in_their_round(traced_round):
     for name, s, e in spans:
         if name != SPAN_ROUND:
             assert any(r0 <= s and e <= r1 for r0, r1 in rounds), name
+
+
+# ---- the Mamba-2 mixer's scopes inside the fused step ----------------------
+
+
+@pytest.fixture(scope="module")
+def ssm_op_names():
+    """Every ``op_name`` of the fused split step of a short Granite 4.0-H
+    stack (mamba, mamba, attention) cut inside its Mamba run, so that both
+    stages hold a Mamba layer."""
+    import dataclasses
+    from repro.core.splitting import SplitExecutor
+    cfg = dataclasses.replace(
+        get_config("granite-4.0-h-micro").reduced(), n_layers=3,
+        layer_types=("mamba", "mamba", "attention"))
+    params = M.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    text = SplitExecutor(cfg).compiled_step.lower(
+        params["frozen"], params["lora"], tokens, tokens,
+        cut=1).compile().as_text()
+    return set(re.findall(r'op_name="((?:[^"\\]|\\.)*)"', text))
+
+
+@pytest.mark.parametrize("scope", [SCOPE_SSM_MIXER, SCOPE_SSM_SCAN])
+@pytest.mark.parametrize("stage", [SCOPE_DEVICE_STAGE, SCOPE_SERVER_LAYERS])
+def test_ssm_scopes_reach_the_fused_step(ssm_op_names, scope, stage):
+    """Forward under ``jvp(<stage>)``, backward under
+    ``transpose(jvp(<stage>))``, each with the scope inside the stage."""
+    inside = [n for n in ssm_op_names if f"/{scope}/" in n
+              and (scope == SCOPE_SSM_MIXER or f"/{SCOPE_SSM_MIXER}/" in n)]
+    fwd = [n for n in inside if f"/jvp({stage})/" in n]
+    bwd = [n for n in inside if f"/transpose(jvp({stage}))/" in n]
+    assert fwd and bwd, (scope, stage)
