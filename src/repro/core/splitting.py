@@ -14,6 +14,10 @@ The compression is a straight-through int8 quantizer: the paper models phi
 as a data-size ratio on the link (Eq. 9); here it is also *executed* so the
 training dynamics include the quantization error.
 
+Attention inside the stages runs the Pallas flash kernels, forward and
+backward, on a TPU, and the naive path, the tests' oracle, elsewhere
+(``attention_impl``).
+
 A cut is a static argument — each cut compiles its own program (cut changes
 at round granularity, Alg. 1). ``SplitExecutor.step`` runs one local epoch
 as one program, ``split_grads_full``: the adapters split at the cut, both
@@ -121,8 +125,17 @@ def merge_lora(dev: Params, srv: Params) -> Params:
     return {"layers": merged}
 
 
+def attention_impl(impl: Optional[str] = None) -> str:
+    """The split step's attention path: ``impl`` if given, else the Pallas
+    flash kernels (forward and backward) on a TPU and the naive oracle
+    elsewhere, where the kernels would only run interpreted."""
+    if impl is not None:
+        return impl
+    return "pallas" if jax.default_backend() == "tpu" else "naive"
+
+
 def device_forward(frozen: Params, lora_dev: Params, inputs: jax.Array,
-                   cfg: ModelConfig, cut: int, *, impl: str = "naive",
+                   cfg: ModelConfig, cut: int, *, impl: Optional[str] = None,
                    compress: bool = True) -> jax.Array:
     """Eq. 2: smashed data at the cut layer (embedding + layers [0,c))."""
     with jax.named_scope(SCOPE_DEVICE_STAGE):
@@ -131,14 +144,14 @@ def device_forward(frozen: Params, lora_dev: Params, inputs: jax.Array,
         else:
             lora_full = {"layers": lora_dev["layers"]}
             x, _ = model_lib.forward_hidden(
-                frozen, lora_full, inputs, cfg, lo=0, hi=cut, impl=impl,
-                remat=False, lora_sliced=True)
+                frozen, lora_full, inputs, cfg, lo=0, hi=cut,
+                impl=attention_impl(impl), remat=False, lora_sliced=True)
     return channel_compress(x, compress)
 
 
 def server_loss(frozen: Params, lora_srv: Params, smashed: jax.Array,
                 labels: jax.Array, cfg: ModelConfig, cut: int, *,
-                impl: str = "naive") -> jax.Array:
+                impl: Optional[str] = None) -> jax.Array:
     """Eq. 3 + loss: layers [c,I) + final norm + head + CE."""
     if cut == cfg.n_layers:
         x, aux = smashed, 0.0
@@ -147,7 +160,7 @@ def server_loss(frozen: Params, lora_srv: Params, smashed: jax.Array,
         with jax.named_scope(SCOPE_SERVER_LAYERS):
             x, aux = model_lib.forward_hidden(
                 frozen, lora_full, smashed, cfg, lo=cut, hi=cfg.n_layers,
-                impl=impl, remat=False, inputs_embedded=True,
+                impl=attention_impl(impl), remat=False, inputs_embedded=True,
                 lora_sliced=True)
     with jax.named_scope(SCOPE_HEAD):
         logits = model_lib.logits_from_hidden(frozen, x, cfg)
@@ -162,7 +175,7 @@ def server_loss(frozen: Params, lora_srv: Params, smashed: jax.Array,
 @functools.partial(jax.jit, static_argnames=("cfg", "cut", "impl", "compress"))
 def split_grads(frozen: Params, lora_dev: Params, lora_srv: Params,
                 inputs: jax.Array, labels: jax.Array, *, cfg: ModelConfig,
-                cut: int, impl: str = "naive", compress: bool = True
+                cut: int, impl: Optional[str] = None, compress: bool = True
                 ) -> Tuple[jax.Array, Params, Params]:
     """Returns (loss, grads_dev, grads_srv) with the smashed-data gradient
     crossing the (compressed) channel boundary — exactly stages 3-4."""
@@ -193,9 +206,10 @@ class SplitExecutor:
     """Runs the split step as one compiled program per cut (Stage 1
     re-splits per round); the program is named ``jit_split_grads_full``."""
 
-    def __init__(self, cfg: ModelConfig, *, impl: str = "naive",
+    def __init__(self, cfg: ModelConfig, *, impl: Optional[str] = None,
                  compress: bool = True):
         self.cfg = cfg
+        impl = attention_impl(impl)
 
         def split_grads_full(frozen: Params, lora: Params, inputs: jax.Array,
                              labels: jax.Array, *, cut: int

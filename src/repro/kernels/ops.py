@@ -47,32 +47,23 @@ def lora_matmul_grouped(x: jax.Array, w: jax.Array, a: jax.Array,
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0,
+                    scale: Optional[float] = None,
                     q_positions=None, k_positions=None,
                     **block_kw) -> jax.Array:
     """GQA-aware wrapper. q: (B, Sq, Hq, D); k, v: (B, Skv, Hkv, D).
+    ``scale``: the softmax scale, 1/sqrt(D) if None. Differentiable.
 
     The kernel assumes positions are 0..S-1; generalized position vectors
     (ring-buffer decode) stay on the jnp path in models/attention.py.
     """
     b, sq, hq, d = q.shape
-    skv, hkv = k.shape[1], k.shape[2]
-    group = hq // hkv
-    # fold (batch, kv_head, group) into the kernel's leading dim; queries of
-    # one group share their KV head
-    qf = (q.reshape(b, sq, hkv, group, d)
-          .transpose(0, 2, 3, 1, 4)
-          .reshape(b * hkv * group, sq, d))
-    kf = (jnp.broadcast_to(k[:, :, :, None, :], (b, skv, hkv, group, d))
-          .transpose(0, 2, 3, 1, 4)
-          .reshape(b * hkv * group, skv, d))
-    vf = (jnp.broadcast_to(v[:, :, :, None, :], (b, skv, hkv, group, d))
-          .transpose(0, 2, 3, 1, 4)
-          .reshape(b * hkv * group, skv, d))
-    out = _fa.flash_attention(qf, kf, vf, causal=causal, window=window,
+    # heads into the kernel's leading dim, batch-major: query row
+    # b * hq + h reads KV row b * hkv + h // group, its group's KV head
+    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, x.shape[1], d)
+    out = _fa.flash_attention(fold(q), fold(k), fold(v), causal=causal,
+                              window=window, sm_scale=scale,
                               interpret=_interpret(), **block_kw)
-    return (out.reshape(b, hkv, group, sq, d)
-            .transpose(0, 3, 1, 2, 4)
-            .reshape(b, sq, hq, d))
+    return out.reshape(b, hq, sq, d).transpose(0, 2, 1, 3)
 
 
 def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,

@@ -5,8 +5,9 @@ Three score paths:
   * ``chunked`` — flash-style online softmax in pure jnp (lax.scan over KV
                   blocks, lax.map over Q blocks). O(S·block) memory; this is
                   the path the multi-pod dry-run lowers.
-  * ``pallas``  — the TPU Pallas kernel in ``repro.kernels.flash_attention``
-                  (validated in interpret mode on CPU).
+  * ``pallas``  — the TPU Pallas kernels in ``repro.kernels.flash_attention``,
+                  forward and backward, for positions 0..S-1 (validated in
+                  interpret mode on CPU); the split step's path on a TPU.
 
 Supports causal masking, sliding windows (SWA), GQA head grouping, RoPE or
 no positions (NoPE, Granite 4.0-H), a published softmax scale in place of
@@ -201,12 +202,9 @@ def attention_scores(q, k, v, *, impl: str, causal: bool, window: int,
                                  q_positions=q_positions, k_positions=k_positions,
                                  scale=scale)
     if impl == "pallas":
-        if scale is not None:
-            raise ValueError("the Pallas attention kernel scales by "
-                             "1/sqrt(head_dim) only")
         from repro.kernels import ops as kernel_ops
         return kernel_ops.flash_attention(q, k, v, causal=causal, window=window,
-                                          q_positions=q_positions,
+                                          scale=scale, q_positions=q_positions,
                                           k_positions=k_positions)
     raise ValueError(f"unknown attention impl {impl!r}")
 

@@ -143,6 +143,8 @@ def forward_hidden(frozen: Params, lora: Optional[Params], inputs: jax.Array,
     if positions is None:
         positions = jnp.broadcast_to(
             jnp.arange(x.shape[1], dtype=jnp.int32), x.shape[:2])
+    elif impl == "pallas":
+        impl = "chunked"      # the kernels assume positions 0..S-1
 
     carry = (x, jnp.zeros((), jnp.float32))
     for kind, start, stop in layer_runs(cfg, lo, hi):

@@ -1,5 +1,7 @@
 """Per-kernel validation: shape/dtype sweeps against the pure-jnp oracles
 (interpret=True executes the exact TPU kernel body on CPU)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -141,6 +143,48 @@ def test_flash_attention_bf16(dtype):
                            q_positions=pos, k_positions=pos)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=3e-2)
+
+
+@pytest.mark.parametrize("window,s,block", [
+    (0, 200, None),       # one 256-row major block of 2 x 2 minor blocks
+    (24, 72, 32),         # three 32-row major blocks, each its own minor
+    (0, 600, 256),        # three 256-row major blocks of 2 x 2 minor blocks
+    (130, 600, 256),      # the same; the window skips whole major blocks
+], ids=["causal", "window24", "causal_major256", "window130_major256"])
+@pytest.mark.parametrize("scale", [None, 1 / 64], ids=["rsqrt_d", "1_64"])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("group", [1, 2, 4])
+def test_flash_attention_grad(group, d, scale, window, s, block):
+    """Forward and ``jax.grad`` in q, k and v against naive_attention, KV
+    heads read through the index maps. The sequence is never a multiple of
+    the block, and the major blocks are one (static) or several (traced)."""
+    blocks = {"block_q": block, "block_k": block} if block else {}
+    hkv = 2
+    kk = jax.random.split(jax.random.PRNGKey(6), 4)
+    q = jax.random.normal(kk[0], (1, s, group * hkv, d))
+    k = jax.random.normal(kk[1], (1, s, hkv, d))
+    v = jax.random.normal(kk[2], (1, s, hkv, d))
+    ct = jax.random.normal(kk[3], q.shape)
+    pos = jnp.broadcast_to(jnp.arange(s), (1, s))
+    from repro.models.attention import naive_attention
+
+    def flash(q, k, v):
+        return ops.flash_attention(q, k, v, causal=True, window=window,
+                                   scale=scale, **blocks)
+
+    def naive(q, k, v):
+        return naive_attention(q, k, v, causal=True, window=window,
+                               q_positions=pos, k_positions=pos, scale=scale)
+
+    @functools.partial(jax.jit, static_argnums=0)
+    def out_and_grads(fn, q, k, v):
+        out, pullback = jax.vjp(fn, q, k, v)
+        return (out, *pullback(ct))
+
+    for got, want in zip(out_and_grads(flash, q, k, v),
+                         out_and_grads(naive, q, k, v), strict=True):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -1,4 +1,6 @@
 """Split execution (Sec. II-B stages 3-4): the SL computation itself."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,6 +22,52 @@ def setup():
     labels = jax.random.randint(jax.random.PRNGKey(6), (4, 32), 0,
                                 cfg.vocab_size)
     return cfg, params, tokens, labels
+
+
+# four layers each, so that cut 2 leaves attention on both sides: qwen3
+# (qk-norm, GQA, RoPE) and a Granite-like mixed stack (Mamba-2 and NoPE
+# attention at the published softmax scale 1/64)
+TINY = {
+    "qwen3": dataclasses.replace(get_config("qwen3-0.6b").reduced(),
+                                 n_layers=4),
+    "granite": dataclasses.replace(
+        get_config("granite-4.0-h-micro").reduced(), n_layers=4,
+        layer_types=("mamba", "attention") * 2),
+}
+
+
+@pytest.mark.parametrize("cut", [0, 2])
+@pytest.mark.parametrize("name", sorted(TINY))
+def test_split_grads_flash_matches_naive(name, cut):
+    """The split step on the flash kernels (interpreted here, as a TPU
+    compiles them) gives the naive path's loss and every adapter gradient.
+    The adapters are random, B included, so that no gradient is zero; the
+    sequence, 40, is not a multiple of the kernels' block."""
+    cfg = TINY[name]
+    params = M.init_params(jax.random.PRNGKey(0), cfg)
+    leaves, tree = jax.tree_util.tree_flatten(params["lora"])
+    keys = jax.random.split(jax.random.PRNGKey(2), len(leaves))
+    lora = jax.tree_util.tree_unflatten(tree, [
+        0.1 * jax.random.normal(key, a.shape, a.dtype)
+        for key, a in zip(keys, leaves, strict=True)])
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 41), 0,
+                                cfg.vocab_size)
+    ld, ls = split_lora(lora, cut, cfg)
+    loss, *grads = split_grads(params["frozen"], ld, ls, tokens[:, :-1],
+                               tokens[:, 1:], cfg=cfg, cut=cut,
+                               impl="pallas", compress=False)
+    want_loss, *want = split_grads(params["frozen"], ld, ls, tokens[:, :-1],
+                                   tokens[:, 1:], cfg=cfg, cut=cut,
+                                   impl="naive", compress=False)
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
+    got = jax.tree_util.tree_leaves_with_path(grads)
+    assert len(got) == len(jax.tree_util.tree_leaves(want)) > 0
+    for (path, a), b in zip(got, jax.tree_util.tree_leaves(want),
+                            strict=True):
+        b = np.asarray(b)
+        np.testing.assert_allclose(np.asarray(a), b,
+                                   atol=1e-4 * np.abs(b).max(initial=0.0),
+                                   err_msg=jax.tree_util.keystr(path))
 
 
 @pytest.mark.parametrize("cut_frac", [0.0, 0.5, 1.0])
