@@ -4,7 +4,7 @@ Validates freshly produced benchmark payloads against their schemas and
 compares their ``gates`` (jitted hot-path wall times, seconds) to the
 committed baseline at the repo root.  Fails (exit 1) when any gated path is
 more than ``--threshold`` times slower than the baseline — by design only
-*jitted* hot paths are gated (``batched_card`` round times, compiled jnp
+*jitted* hot paths are gated (``tiered_card_grid`` round times, compiled jnp
 kernel probes); Pallas interpret-mode times are never emitted as gates
 because CPU interpret mode is far too noisy to gate.
 

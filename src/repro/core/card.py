@@ -20,8 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.cost_model import (BatchedRoundContext, RoundContext,
-                                   TieredRoundContext)
+from repro.core.cost_model import RoundContext, TieredRoundContext
 
 
 @dataclass(frozen=True)
@@ -172,12 +171,20 @@ def random_cut(ctx: RoundContext, rng: np.random.Generator) -> Decision:
 
 
 # ---------------------------------------------------------------------------
-# Batched CARD — the whole (rounds x devices x cuts) grid under jit
+# Array CARD — the whole (servers x rounds x devices x cuts) grid under jit
 # ---------------------------------------------------------------------------
+#
+# One implementation for the paper's single-server fleet (an S = 1 tier,
+# ``scheduler.simulate_fleet``) and for the hierarchical tier below.
 
 
-class BatchedDecision(NamedTuple):
-    """Per-(round, device) decisions; every field is an (R, D) array."""
+class TierDecision(NamedTuple):
+    """Per-(server, round, device) decisions; every field is an (S, R, D)
+    array. ``costs`` is what the assignment stage prices; ``delays`` are
+    seconds, ``energies`` joules, ``freqs`` Hz. The ``d_*`` fields split
+    ``delays`` into its four Eq. 10 terms; ``d_server`` is the share that
+    contends when one server hosts many devices (parallel-SL round
+    folding)."""
     cuts: jnp.ndarray         # int32
     freqs: jnp.ndarray        # Hz
     costs: jnp.ndarray        # Eq. 12 scalarized cost
@@ -189,133 +196,148 @@ class BatchedDecision(NamedTuple):
     d_downlink: jnp.ndarray   #                  downlink (grads + adapters)
 
 
-def batched_optimal_frequency(bctx: BatchedRoundContext,
-                              corners=None) -> jnp.ndarray:
-    """Eq. (16) per (round, device): Q depends only on the corners, which
-    depend on the channel draw — hence an (R, D) array of f*."""
+def _f_max(tctx: TieredRoundContext) -> jnp.ndarray:
+    """Each server flat out, broadcast to the (S, R, D) lattice."""
+    return jnp.broadcast_to(tctx.server_f_max[:, None, None], tctx.shape)
+
+
+def tiered_optimal_frequency(tctx: TieredRoundContext,
+                             corners=None) -> jnp.ndarray:
+    """Eq. (16) per (server, round, device): Q depends only on the corners,
+    which depend on the channel draw and the server's DVFS bounds — hence
+    an (S, R, D) array of f*."""
     if corners is None:
-        corners = bctx.corners()
+        corners = tctx.corners()
     d_min, d_max, e_min, e_max = corners
-    # w is traced (see BatchedRoundContext): guard the 1-w division and
+    # w is traced (see TieredRoundContext): guard the 1-w division and
     # select the pure-delay w=1 endpoint with where, not Python control flow
-    q = ((bctx.w * (e_max - e_min))
-         / (2.0 * bctx.xi * jnp.maximum(1.0 - bctx.w, 1e-12)
+    q = ((tctx.w * (e_max - e_min))
+         / (2.0 * tctx.xi * jnp.maximum(1.0 - tctx.w, 1e-12)
             * jnp.maximum(d_max - d_min, 1e-12))) ** (1.0 / 3.0)
-    f = jnp.clip(q, bctx.f_min()[None, :], bctx.server_f_max)
-    return jnp.where(bctx.w >= 1.0, bctx.server_f_max, f)
+    f_hi = _f_max(tctx)
+    f = jnp.clip(q, tctx.f_min()[:, None, :], f_hi)
+    return jnp.where(tctx.w >= 1.0, f_hi, f)
 
 
-def _batched_evaluate(bctx: BatchedRoundContext, cuts: jnp.ndarray,
-                      f: jnp.ndarray, corners,
-                      deadline: Optional[DeadlineSpec] = None
-                      ) -> BatchedDecision:
-    """Metrics for fixed per-(round, device) decisions (cuts, f): (R, D)."""
+def _tiered_evaluate(tctx: TieredRoundContext, cuts: jnp.ndarray,
+                     f: jnp.ndarray, corners,
+                     deadline: Optional[DeadlineSpec] = None
+                     ) -> TierDecision:
+    """Metrics for fixed per-(server, round, device) decisions (cuts, f)."""
     c = cuts[..., None]
-    parts = bctx.delay_components(c, f)
+    parts = tctx.delay_components(c, f)
     delays = parts.total[..., 0]
-    costs = bctx.cost(c, f, corners)[..., 0]
+    costs = tctx.cost(c, f, corners)[..., 0]
     if deadline is not None:
         costs = costs + deadline.penalty * miss_probability(delays, deadline)
-    return BatchedDecision(
+    return TierDecision(
         cuts=cuts.astype(jnp.int32),
-        freqs=jnp.broadcast_to(f, bctx.shape),
+        freqs=jnp.broadcast_to(f, tctx.shape),
         costs=costs,
         delays=delays,
-        energies=bctx.server_energy(c, f)[..., 0],
+        energies=tctx.server_energy(c, f)[..., 0],
         d_device=parts.device_comp[..., 0], d_uplink=parts.uplink[..., 0],
         d_server=parts.server_comp[..., 0], d_downlink=parts.downlink[..., 0])
 
 
+def _mask_infeasible(tctx: TieredRoundContext, grid: jnp.ndarray,
+                     cost: jnp.ndarray) -> jnp.ndarray:
+    """inf out the cuts above each device's memory cap."""
+    infeasible = grid[None, :] > tctx.max_cut[:, None]         # (D, C)
+    return jnp.where(infeasible, jnp.inf, cost)
+
+
 @partial(jax.jit, static_argnames=("respect_memory",))
-def batched_card(bctx: BatchedRoundContext, *,
-                 respect_memory: bool = True,
-                 deadline: Optional[DeadlineSpec] = None) -> BatchedDecision:
-    """Alg. 1 for the whole fleet: closed-form f* per (round, device), then
-    the brute-force over cuts becomes one argmin over the cost tensor.
+def tiered_card_grid(tctx: TieredRoundContext, *,
+                     respect_memory: bool = True,
+                     deadline: Optional[DeadlineSpec] = None
+                     ) -> TierDecision:
+    """Alg. 1 for every candidate server at once: closed-form f* per
+    (server, round, device), then the brute-force over cuts becomes one
+    argmin over the (S, R, D, C) cost tensor. Stage 2 of
+    ``hierarchical_card``; on an S = 1 tier, the paper's fleet decision.
     ``deadline`` adds the straggler-aware miss-probability penalty and the
     per-cut f_max rescue candidate (same objective as the scalar path)."""
-    corners = bctx.corners()
-    f_star = batched_optimal_frequency(bctx, corners)
-    grid = jnp.arange(bctx.n_cuts)
+    corners = tctx.corners()
+    f_star = tiered_optimal_frequency(tctx, corners)
+    grid = jnp.arange(tctx.n_cuts)
     freqs = f_star
-    cost = bctx.cost(grid, f_star, corners)                 # (R, D, C)
+    cost = tctx.cost(grid, f_star, corners)                  # (S, R, D, C)
     # structural None checks below: recompile only when the deadline
     # objective is toggled on/off, never per value
     # splint: ignore[trace-safety]
     if deadline is not None:
         def penalized(f):
-            base = bctx.cost(grid, f, corners)              # (R, D, C)
+            base = tctx.cost(grid, f, corners)               # (S, R, D, C)
             return base + deadline.penalty * miss_probability(
-                bctx.round_delay(grid, f), deadline)
+                tctx.round_delay(grid, f), deadline)
 
         cost = penalized(f_star)
-        rescue_cost = penalized(jnp.full(bctx.shape, bctx.server_f_max))
-        use_rescue = rescue_cost < cost                     # strict, like
-        cost = jnp.where(use_rescue, rescue_cost, cost)     # the scalar path
+        rescue_cost = penalized(_f_max(tctx))
+        use_rescue = rescue_cost < cost                      # strict, like
+        cost = jnp.where(use_rescue, rescue_cost, cost)      # the scalar path
     if respect_memory:
-        infeasible = grid[None, None, :] > bctx.max_cut[None, :, None]
-        cost = jnp.where(infeasible, jnp.inf, cost)
-    best = jnp.argmin(cost, axis=-1).astype(jnp.int32)      # (R, D)
+        cost = _mask_infeasible(tctx, grid, cost)
+    best = jnp.argmin(cost, axis=-1).astype(jnp.int32)       # (S, R, D)
     # splint: ignore[trace-safety]
     if deadline is not None:
         picked = jnp.take_along_axis(use_rescue, best[..., None],
                                      axis=-1)[..., 0]
-        freqs = jnp.where(picked, bctx.server_f_max, f_star)
-    return _batched_evaluate(bctx, best, freqs, corners, deadline)
+        freqs = jnp.where(picked, _f_max(tctx), f_star)
+    return _tiered_evaluate(tctx, best, freqs, corners, deadline)
 
 
 @partial(jax.jit, static_argnames=("n_freq", "respect_memory"))
-def batched_card_joint_bruteforce(bctx: BatchedRoundContext, *,
-                                  n_freq: int = 200,
-                                  respect_memory: bool = True
-                                  ) -> BatchedDecision:
+def tiered_card_joint_bruteforce(tctx: TieredRoundContext, *,
+                                 n_freq: int = 200,
+                                 respect_memory: bool = True
+                                 ) -> TierDecision:
     """Exhaustive (f, c) grid, vmapped over the frequency axis — the
-    optimality oracle for the batched path. O(F * R * D * C) memory: use
+    optimality oracle for the array path. O(F * S * R * D * C) memory: use
     small fleets (tests), not production sweeps."""
-    corners = bctx.corners()
-    grid = jnp.arange(bctx.n_cuts)
-    fgrid = jnp.linspace(bctx.f_min(), bctx.server_f_max, n_freq)  # (F, D)
+    corners = tctx.corners()
+    grid = jnp.arange(tctx.n_cuts)
+    fgrid = jnp.linspace(tctx.f_min(), tctx.server_f_max[:, None],
+                         n_freq)                             # (F, S, D)
 
     def cost_at(fk):
-        cost = bctx.cost(grid, jnp.broadcast_to(fk, bctx.shape), corners)
-        if respect_memory:
-            infeasible = grid[None, None, :] > bctx.max_cut[None, :, None]
-            cost = jnp.where(infeasible, jnp.inf, cost)
-        return cost
+        cost = tctx.cost(grid, jnp.broadcast_to(fk[:, None, :], tctx.shape),
+                         corners)
+        return _mask_infeasible(tctx, grid, cost) if respect_memory else cost
 
-    costs = jax.vmap(cost_at)(fgrid)                        # (F, R, D, C)
-    n_dev = bctx.shape[1]
-    flat = jnp.moveaxis(costs, 0, -1)                       # (R, D, C, F)
-    flat = flat.reshape(bctx.shape + (bctx.n_cuts * n_freq,))
+    costs = jax.vmap(cost_at)(fgrid)                         # (F, S, R, D, C)
+    flat = jnp.moveaxis(costs, 0, -1)                        # (S, R, D, C, F)
+    flat = flat.reshape(tctx.shape + (tctx.n_cuts * n_freq,))
     idx = jnp.argmin(flat, axis=-1)
     best_c = (idx // n_freq).astype(jnp.int32)
-    f_sel = fgrid[idx % n_freq, jnp.arange(n_dev)[None, :]]
-    return _batched_evaluate(bctx, best_c, f_sel, corners)
+    n_srv, _, n_dev = tctx.shape
+    f_sel = fgrid[idx % n_freq, jnp.arange(n_srv)[:, None, None],
+                  jnp.arange(n_dev)[None, None, :]]
+    return _tiered_evaluate(tctx, best_c, f_sel, corners)
 
 
-def batched_server_only(bctx: BatchedRoundContext) -> BatchedDecision:
-    """Baseline: cut 0 (everything on the server) at ``server_f_max`` Hz
-    for every (round, device) lane; all outputs are (R, D)."""
-    cuts = jnp.zeros(bctx.shape, jnp.int32)
-    return _batched_evaluate(bctx, cuts,
-                             jnp.full(bctx.shape, bctx.server_f_max),
-                             bctx.corners())
+def tiered_server_only(tctx: TieredRoundContext) -> TierDecision:
+    """Baseline: cut 0 (everything on the server) at the server's
+    ``f_max`` Hz for every (server, round, device) lane."""
+    cuts = jnp.zeros(tctx.shape, jnp.int32)
+    return _tiered_evaluate(tctx, cuts, _f_max(tctx), tctx.corners())
 
 
-def batched_device_only(bctx: BatchedRoundContext) -> BatchedDecision:
+def tiered_device_only(tctx: TieredRoundContext) -> TierDecision:
     """Baseline: cut n_layers (everything on the device) at the minimum
-    feasible server frequency in Hz; all outputs are (R, D)."""
-    cuts = jnp.full(bctx.shape, bctx.n_cuts - 1, jnp.int32)
-    f = jnp.broadcast_to(bctx.f_min(), bctx.shape)
-    return _batched_evaluate(bctx, cuts, f, bctx.corners())
+    feasible server frequency in Hz."""
+    cuts = jnp.full(tctx.shape, tctx.n_cuts - 1, jnp.int32)
+    f = jnp.broadcast_to(tctx.f_min()[:, None, :], tctx.shape)
+    return _tiered_evaluate(tctx, cuts, f, tctx.corners())
 
 
-def batched_static_cut(bctx: BatchedRoundContext, cut) -> BatchedDecision:
-    """``cut`` may be a scalar or an (R, D) array (e.g. random-cut draws)."""
-    corners = bctx.corners()
-    f_star = batched_optimal_frequency(bctx, corners)
-    cuts = jnp.broadcast_to(jnp.asarray(cut, jnp.int32), bctx.shape)
-    return _batched_evaluate(bctx, cuts, f_star, corners)
+def tiered_static_cut(tctx: TieredRoundContext, cut) -> TierDecision:
+    """``cut`` may be a scalar or an (R, D) array (e.g. random-cut draws),
+    the same on every server; the frequency is Eq. 16's."""
+    corners = tctx.corners()
+    f_star = tiered_optimal_frequency(tctx, corners)
+    cuts = jnp.broadcast_to(jnp.asarray(cut, jnp.int32), tctx.shape)
+    return _tiered_evaluate(tctx, cuts, f_star, corners)
 
 
 # ---------------------------------------------------------------------------
@@ -330,62 +352,6 @@ def batched_static_cut(bctx: BatchedRoundContext, cut) -> BatchedDecision:
 # stages decouple the same way Eq. 16 decouples f from c: the per-server
 # grids are computed once for all S servers and the assignment is a pure
 # host-side matching over the (S, D) price matrix.
-
-
-class TierDecision(NamedTuple):
-    """Per-(server, round, device) best-response CARD grids; every field is
-    an (S, R, D) array. ``costs`` is what the assignment stage prices;
-    ``delays`` are seconds, ``energies`` joules, ``freqs`` Hz. ``d_server``
-    is the server-compute share of ``delays`` — the term that contends when
-    one server hosts many devices (parallel-SL round folding)."""
-    cuts: jnp.ndarray
-    freqs: jnp.ndarray
-    costs: jnp.ndarray
-    delays: jnp.ndarray
-    energies: jnp.ndarray
-    d_server: jnp.ndarray
-
-
-def tiered_optimal_frequency(tctx: TieredRoundContext,
-                             corners=None) -> jnp.ndarray:
-    """Eq. (16) per (server, round, device): same closed form as
-    :func:`batched_optimal_frequency` with per-server DVFS bounds."""
-    if corners is None:
-        corners = tctx.corners()
-    d_min, d_max, e_min, e_max = corners
-    q = ((tctx.w * (e_max - e_min))
-         / (2.0 * tctx.xi * jnp.maximum(1.0 - tctx.w, 1e-12)
-            * jnp.maximum(d_max - d_min, 1e-12))) ** (1.0 / 3.0)
-    f_hi = jnp.broadcast_to(tctx.server_f_max[:, None, None], tctx.shape)
-    f = jnp.clip(q, tctx.f_min()[:, None, :], f_hi)
-    return jnp.where(tctx.w >= 1.0, f_hi, f)
-
-
-@partial(jax.jit, static_argnames=("respect_memory",))
-def tiered_card_grid(tctx: TieredRoundContext, *,
-                     respect_memory: bool = True) -> TierDecision:
-    """Alg. 1 for every candidate server at once: closed-form f* per
-    (server, round, device), then one argmin over the (S, R, D, C) cost
-    tensor. Stage 2 of ``hierarchical_card`` — and, gathered along the
-    assignment, identical to running ``batched_card`` per server."""
-    corners = tctx.corners()
-    f_star = tiered_optimal_frequency(tctx, corners)
-    grid = jnp.arange(tctx.n_cuts)
-    cost = tctx.cost(grid, f_star, corners)                  # (S, R, D, C)
-    if respect_memory:
-        infeasible = grid[None, None, None, :] \
-            > tctx.max_cut[None, None, :, None]
-        cost = jnp.where(infeasible, jnp.inf, cost)
-    best = jnp.argmin(cost, axis=-1).astype(jnp.int32)       # (S, R, D)
-    c = best[..., None]
-    parts = tctx.delay_components(c, f_star)
-    return TierDecision(
-        cuts=best,
-        freqs=f_star,
-        costs=tctx.cost(c, f_star, corners)[..., 0],
-        delays=parts.total[..., 0],
-        energies=tctx.server_energy(c, f_star)[..., 0],
-        d_server=parts.server_comp[..., 0])
 
 
 ASSIGN_METHODS = ("greedy", "optimal")
@@ -530,7 +496,7 @@ class HierarchicalDecision(NamedTuple):
     ``assignment`` — (D,) int server index per device; ``cuts``/``freqs``/
     ``costs``/``delays``/``energies``/``d_server`` — (R, D) per-device
     decisions under the assigned server (seconds / joules / Hz, as in
-    BatchedDecision; ``d_server`` is the server-compute share of
+    TierDecision; ``d_server`` is the server-compute share of
     ``delays``); ``aggregation_s`` — (S, R) per-server backhaul aggregation
     delay; ``server_load`` — (S,) devices per server.
     """
@@ -555,6 +521,29 @@ def _gather_assigned(grid: TierDecision, assign: np.ndarray
             for field in TierDecision._fields}
 
 
+def _assigned_decision(tctx: TieredRoundContext, grid: TierDecision,
+                       a: np.ndarray) -> HierarchicalDecision:
+    """Read each device's decisions off its assigned server's grid and
+    price the per-server backhaul aggregation."""
+    picked = _gather_assigned(grid, a)
+    assign_mask = a[None, :] == np.arange(tctx.n_servers)[:, None]
+    agg = jax.device_get(tctx.aggregation_delay(
+        jnp.asarray(assign_mask), jnp.asarray(picked["cuts"])))
+    return HierarchicalDecision(
+        assignment=a.astype(np.int64),
+        cuts=picked["cuts"].astype(np.int32),
+        freqs=picked["freqs"], costs=picked["costs"],
+        delays=picked["delays"], energies=picked["energies"],
+        d_server=picked["d_server"],
+        aggregation_s=np.asarray(agg),
+        server_load=assign_mask.sum(axis=1).astype(np.int64))
+
+
+def _price_matrix(grid: TierDecision) -> np.ndarray:
+    """(S, D) float64: each pair's optimal CARD cost, mean over rounds."""
+    return np.asarray(jax.device_get(grid.costs), np.float64).mean(axis=1)
+
+
 def hierarchical_card(tctx: TieredRoundContext, *,
                       respect_memory: bool = True,
                       assign: str = "greedy") -> HierarchicalDecision:
@@ -571,20 +560,9 @@ def hierarchical_card(tctx: TieredRoundContext, *,
     ``assign="optimal"`` (tested on fleets <= 8 devices x 2 servers).
     """
     grid = tiered_card_grid(tctx, respect_memory=respect_memory)
-    cost_sd = np.asarray(jax.device_get(grid.costs), np.float64).mean(axis=1)
-    a = assign_devices(cost_sd, np.asarray(tctx.capacity), method=assign)
-    picked = _gather_assigned(grid, a)
-    assign_mask = a[None, :] == np.arange(tctx.n_servers)[:, None]
-    agg = jax.device_get(tctx.aggregation_delay(
-        jnp.asarray(assign_mask), jnp.asarray(picked["cuts"])))
-    return HierarchicalDecision(
-        assignment=a.astype(np.int64),
-        cuts=picked["cuts"].astype(np.int32),
-        freqs=picked["freqs"], costs=picked["costs"],
-        delays=picked["delays"], energies=picked["energies"],
-        d_server=picked["d_server"],
-        aggregation_s=np.asarray(agg),
-        server_load=assign_mask.sum(axis=1).astype(np.int64))
+    a = assign_devices(_price_matrix(grid), np.asarray(tctx.capacity),
+                       method=assign)
+    return _assigned_decision(tctx, grid, a)
 
 
 def hierarchical_card_exhaustive(tctx: TieredRoundContext, *,
@@ -593,20 +571,8 @@ def hierarchical_card_exhaustive(tctx: TieredRoundContext, *,
     """The test oracle: exhaustive assignment enumeration over the same
     price matrix, then the identical per-server decision readout."""
     grid = tiered_card_grid(tctx, respect_memory=respect_memory)
-    cost_sd = np.asarray(jax.device_get(grid.costs), np.float64).mean(axis=1)
-    a = exhaustive_assignment(cost_sd, np.asarray(tctx.capacity))
-    picked = _gather_assigned(grid, a)
-    assign_mask = a[None, :] == np.arange(tctx.n_servers)[:, None]
-    agg = jax.device_get(tctx.aggregation_delay(
-        jnp.asarray(assign_mask), jnp.asarray(picked["cuts"])))
-    return HierarchicalDecision(
-        assignment=a.astype(np.int64),
-        cuts=picked["cuts"].astype(np.int32),
-        freqs=picked["freqs"], costs=picked["costs"],
-        delays=picked["delays"], energies=picked["energies"],
-        d_server=picked["d_server"],
-        aggregation_s=np.asarray(agg),
-        server_load=assign_mask.sum(axis=1).astype(np.int64))
+    a = exhaustive_assignment(_price_matrix(grid), np.asarray(tctx.capacity))
+    return _assigned_decision(tctx, grid, a)
 
 
 def hierarchical_card_scalar(workload, devices, tier, channels, sim, *,

@@ -115,7 +115,7 @@ def head_fwd_flops_per_token(cfg: ModelConfig) -> float:
 LORA_TRAIN_FACTOR = 2.0
 
 # Fraction of device RAM the frozen backbone may occupy (the rest is
-# activations/runtime). Shared by the scalar and batched feasibility masks.
+# activations/runtime). Shared by the scalar and array feasibility masks.
 MEM_BUDGET_FRACTION = 0.8
 
 
@@ -352,71 +352,45 @@ class RoundContext:
 
 
 # ---------------------------------------------------------------------------
-# Batched fleet context — array-in/array-out Eqs. 7-12
+# Fleet context — array-in/array-out Eqs. 7-12 over a tier of servers
 # ---------------------------------------------------------------------------
 
 
-def _per_cut_tables(workload: Workload, sim: SimParams, compute) -> dict:
-    """Float64 per-cut tables shared by the batched and tiered contexts.
-
-    One accounting for both: ``dev_flops``/``srv_flops`` (effective FLOPs,
-    Eqs. 7-8), ``up_bits``/``down_bits`` (per-local-epoch phi-compressed
-    smashed/gradient bits, Eq. 9), ``adapter_bits`` (once-per-round adapter
-    exchange bits), ``weight_bytes`` (frozen device-side backbone bytes for
-    the memory-feasibility mask). Every array has shape ``(C,)`` with
-    ``C = n_layers + 1`` candidate cuts.
-    """
-    cuts = range(workload.cfg.n_layers + 1)
-    return {
-        "dev_flops": np.array([compute.device_flops(c) for c in cuts]),
-        "srv_flops": np.array([compute.server_flops(c) for c in cuts]),
-        "up_bits": np.array([8 * sim.phi * workload.smashed_bytes(
-            c, sim.act_bytes) for c in cuts]),
-        "down_bits": np.array([8 * sim.phi * workload.gradient_bytes(
-            c, sim.act_bytes) for c in cuts]),
-        "adapter_bits": np.array([8 * workload.adapter_bytes(
-            c, sim.adapter_bytes) for c in cuts]),
-        "weight_bytes": np.array([workload.device_weight_bytes(c)
-                                  for c in cuts]),
-    }
-
-
-def _max_cut_per_device(weight_bytes: np.ndarray,
-                        mem_bytes: np.ndarray) -> np.ndarray:
-    """Largest feasible cut per device: the frozen device-side weights at
-    cut c must fit ``MEM_BUDGET_FRACTION`` of device RAM. ``weight_bytes``
-    is the per-cut ``(C,)`` table, ``mem_bytes`` the ``(D,)`` fleet array;
-    returns int ``(D,)`` (0 when not even the embedding fits)."""
-    feas = (weight_bytes[None, :]
-            <= MEM_BUDGET_FRACTION * mem_bytes[:, None])       # (D, C)
-    return np.where(feas.any(axis=1),
-                    feas.shape[1] - 1 - np.argmax(feas[:, ::-1], axis=1),
-                    0)
-
-
 @dataclass(frozen=True)
-class BatchedRoundContext:
-    """``RoundContext`` for a whole fleet sweep at once.
+class TieredRoundContext:
+    """``RoundContext`` for a whole fleet sweep against a
+    :class:`~repro.core.hardware.ServerTier`, the one array context.
+
+    The hierarchical-SL setting (SplitLLM, arXiv:2501.13318): a tier of
+    ``S`` edge servers, each with its own DVFS range and backhaul link to
+    the aggregator, shared by one fleet of ``D`` devices. The paper's
+    single-server problem is the ``S = 1`` tier (``simulate_fleet``).
 
     Per-cut tables are precomputed in float64 from the scalar ComputeSource
     — analytic ``Workload`` FLOPs or a measured ``LatencyTable``, selected
     by ``build(..., cost_source=...)`` exactly as in ``RoundContext`` (so
-    scalar and batched paths share one accounting), then cast to the
-    active jnp precision — float32 unless ``jax_enable_x64`` — and the
-    delay/energy/cost algebra runs as jnp broadcasting over a ``(rounds,
-    devices, cuts)`` tensor. The bimodal cost structure (Fig. 3) keeps the
-    argmin far from float32 eps in practice, but a pathologically
-    near-tied fleet could pick the other endpoint than the float64 scalar
-    oracle. Shape conventions:
+    the scalar and array paths share one accounting), then cast to the
+    active jnp precision — float32 unless ``jax_enable_x64``. The bimodal
+    cost structure (Fig. 3) keeps the argmin far from float32 eps in
+    practice, but a pathologically near-tied fleet could pick the other
+    endpoint than the float64 scalar oracle. Shape conventions:
 
-      tables       (C,)    — C = n_layers + 1 candidate cuts
-      per-device   (D,)
-      channel      (R, D)  — one link realization per (round, device)
+      per-cut tables  (C,)     — C = n_layers + 1 candidate cuts;
+                                 device-side quantities, server-agnostic
+      per-device      (D,)
+      channel         (R, D)   — the device's radio link to its access
+                                 point, shared across candidate servers
+      per-server      (S,)     — throughput/Hz, DVFS bounds, backhaul
 
-    ``cuts`` arguments index the tables and may be any shape broadcastable
-    against trailing layout ``(R, D, C')`` (typically ``(C,)`` for the full
-    grid, or ``(R, D, 1)`` for per-decision evaluation); ``f`` is a scalar
-    or an ``(R, D)`` per-decision frequency.
+    ``delay_components``/``cost``/``server_energy`` broadcast over
+    ``(S, R, D, C')``; ``corners`` is per ``(S, R, D)``.
+
+    Lanes of devices *not* assigned to a server are masked to NaN by
+    :meth:`mask_unassigned` — downstream reductions must be NaN-aware,
+    exactly like the churn layer's survivor masking.
+
+    Units follow the repo suffix registry: ``*_s`` seconds, ``*_bits``
+    bits, ``*_flops`` effective FLOPs, frequencies in Hz, energies in J.
     """
     # per-cut tables (C,)
     dev_flops: jnp.ndarray       # eta_D(c), fwd+bwd FLOPs
@@ -430,172 +404,13 @@ class BatchedRoundContext:
     # per-(round, device) (R, D)
     rate_up: jnp.ndarray
     rate_down: jnp.ndarray
-    # Eq. 12 weights as 0-d arrays (data, not jit-static: a w-sweep like
-    # ablation_pareto must reuse one compiled grid across all w values)
-    w: jnp.ndarray
-    xi: jnp.ndarray
-    # static hyperparameters (pytree aux data)
-    local_epochs: int
-    server_tp_per_hz: float      # delta_S * sigma_S
-    server_f_max: float
-    server_f_min: float
-
-    @classmethod
-    def build(cls, workload: Workload, devices: Sequence[DeviceProfile],
-              server: DeviceProfile, channels: ChannelBatch,
-              sim: SimParams, *, cost_source: str = "analytic",
-              latency_table=None) -> "BatchedRoundContext":
-        compute = resolve_compute(workload, cost_source, latency_table)
-        tables = _per_cut_tables(workload, sim, compute)
-        arrs = fleet_arrays(devices)
-        # memory feasibility: largest c whose frozen weights fit the budget
-        max_cut = _max_cut_per_device(tables["weight_bytes"],
-                                      arrs["mem_bytes"])
-        return cls(
-            dev_flops=jnp.asarray(tables["dev_flops"]),
-            srv_flops=jnp.asarray(tables["srv_flops"]),
-            up_bits=jnp.asarray(tables["up_bits"]),
-            down_bits=jnp.asarray(tables["down_bits"]),
-            adapter_bits=jnp.asarray(tables["adapter_bits"]),
-            peak_flops=jnp.asarray(arrs["peak_flops"]),
-            max_cut=jnp.asarray(max_cut, jnp.int32),
-            rate_up=jnp.asarray(channels.rate_up),
-            rate_down=jnp.asarray(channels.rate_down),
-            w=jnp.asarray(float(sim.w)), xi=jnp.asarray(float(sim.xi)),
-            local_epochs=int(sim.local_epochs),
-            server_tp_per_hz=float(server.delta * server.sigma),
-            server_f_max=float(server.f_max), server_f_min=float(server.f_min))
-
-    # -- shapes --------------------------------------------------------------
-    @property
-    def n_cuts(self) -> int:
-        return self.dev_flops.shape[0]
-
-    @property
-    def shape(self) -> Tuple[int, int]:
-        return self.rate_up.shape
-
-    def _f_expand(self, f) -> jnp.ndarray:
-        f = jnp.asarray(f)
-        return f[..., None] if f.ndim == 2 else f
-
-    # -- Sec. III-C feasible frequency floor, per device ---------------------
-    def f_min(self) -> jnp.ndarray:
-        return jnp.maximum(self.peak_flops / self.server_tp_per_hz,
-                           self.server_f_min)
-
-    # -- Eqs. 7-10, per component -------------------------------------------
-    def delay_components(self, cuts, f) -> DelayBreakdown:
-        cuts = jnp.asarray(cuts)
-        f = self._f_expand(f)
-        t = self.local_epochs
-        dev = t * self.dev_flops[cuts] / self.peak_flops[:, None]
-        srv = t * self.srv_flops[cuts] / (f * self.server_tp_per_hz)
-        up = ((t * self.up_bits[cuts] + self.adapter_bits[cuts])
-              / self.rate_up[..., None])
-        down = ((t * self.down_bits[cuts] + self.adapter_bits[cuts])
-                / self.rate_down[..., None])
-        dev, up, srv, down = jnp.broadcast_arrays(dev, up, srv, down)
-        return DelayBreakdown(device_comp=dev, uplink=up,
-                              server_comp=srv, downlink=down)
-
-    def round_delay(self, cuts, f) -> jnp.ndarray:
-        return self.delay_components(cuts, f).total
-
-    # -- Eq. 11 --------------------------------------------------------------
-    def server_energy(self, cuts, f) -> jnp.ndarray:
-        cuts = jnp.asarray(cuts)
-        f = self._f_expand(f)
-        return (self.local_epochs * self.xi * f ** 2 * self.srv_flops[cuts]
-                / self.server_tp_per_hz)
-
-    # -- normalization corners (Sec. III-C), each (R, D) ---------------------
-    def corners(self) -> Tuple[jnp.ndarray, jnp.ndarray,
-                               jnp.ndarray, jnp.ndarray]:
-        last = jnp.array([self.n_cuts - 1])
-        first = jnp.array([0])
-        f_lo = jnp.broadcast_to(self.f_min(), self.shape)
-        f_hi = jnp.full(self.shape, self.server_f_max)
-        d_max = self.round_delay(last, f_lo)[..., 0]
-        e_min = self.server_energy(last, f_lo)[..., 0]
-        d_min = self.round_delay(first, f_hi)[..., 0]
-        e_max = self.server_energy(first, f_hi)[..., 0]
-        return d_min, d_max, e_min, e_max
-
-    # -- Eq. 12 --------------------------------------------------------------
-    def cost(self, cuts, f, corners=None) -> jnp.ndarray:
-        if corners is None:
-            corners = self.corners()
-        d_min, d_max, e_min, e_max = corners
-        d = self.round_delay(cuts, f)
-        e = self.server_energy(cuts, f)
-        dn = ((d - d_min[..., None])
-              / jnp.maximum(d_max - d_min, 1e-12)[..., None])
-        en = ((e - e_min[..., None])
-              / jnp.maximum(e_max - e_min, 1e-12)[..., None])
-        return self.w * dn + (1 - self.w) * en
-
-
-jax.tree_util.register_dataclass(
-    BatchedRoundContext,
-    data_fields=["dev_flops", "srv_flops", "up_bits", "down_bits",
-                 "adapter_bits", "peak_flops", "max_cut", "rate_up",
-                 "rate_down", "w", "xi"],
-    meta_fields=["local_epochs", "server_tp_per_hz",
-                 "server_f_max", "server_f_min"])
-
-
-# ---------------------------------------------------------------------------
-# Tiered fleet context — Eqs. 7-12 with a leading server axis
-# ---------------------------------------------------------------------------
-
-
-@dataclass(frozen=True)
-class TieredRoundContext:
-    """``BatchedRoundContext`` for a :class:`~repro.core.hardware.ServerTier`.
-
-    The hierarchical-SL setting (SplitLLM, arXiv:2501.13318): a tier of
-    ``S`` edge servers, each with its own DVFS range and backhaul link to
-    the aggregator, shared by one fleet of ``D`` devices. Every delay /
-    energy / cost tensor gains a leading server axis:
-
-      per-cut tables  (C,)     — device-side quantities, server-agnostic
-      per-device      (D,)
-      channel         (R, D)   — the device's radio link to its access
-                                 point, shared across candidate servers
-      per-server      (S,)     — throughput/Hz, DVFS bounds, backhaul
-
-    ``delay_components``/``cost``/``server_energy`` broadcast over
-    ``(S, R, D, C')``; ``corners`` is per ``(S, R, D)``. An ``S = 1`` tier
-    is numerically identical to the single-server batched context (the
-    per-server parameters appear in exactly the same algebraic positions
-    — equivalence-tested in ``tests/test_hierarchy.py``).
-
-    Lanes of devices *not* assigned to a server are masked to NaN by
-    :meth:`mask_unassigned` — downstream reductions must be NaN-aware,
-    exactly like the churn layer's survivor masking.
-
-    Units follow the repo suffix registry: ``*_s`` seconds, ``*_bits``
-    bits, ``*_flops`` effective FLOPs, frequencies in Hz, energies in J.
-    """
-    # per-cut tables (C,)
-    dev_flops: jnp.ndarray
-    srv_flops: jnp.ndarray
-    up_bits: jnp.ndarray
-    down_bits: jnp.ndarray
-    adapter_bits: jnp.ndarray
-    # per-device (D,)
-    peak_flops: jnp.ndarray
-    max_cut: jnp.ndarray
-    # per-(round, device) (R, D)
-    rate_up: jnp.ndarray
-    rate_down: jnp.ndarray
     # per-server (S,)
     server_tp_per_hz: jnp.ndarray   # delta_S * sigma_S
     server_f_max: jnp.ndarray       # Hz
     server_f_min: jnp.ndarray       # Hz
     backhaul_bits_per_s: jnp.ndarray
-    # Eq. 12 weights as 0-d arrays (data, not jit-static)
+    # Eq. 12 weights as 0-d arrays (data, not jit-static: a w-sweep like
+    # ablation_pareto must reuse one compiled grid across all w values)
     w: jnp.ndarray
     xi: jnp.ndarray
     # static hyperparameters (pytree aux data)
@@ -608,21 +423,36 @@ class TieredRoundContext:
               tier: ServerTier, channels: ChannelBatch, sim: SimParams, *,
               cost_source: str = "analytic",
               latency_table=None) -> "TieredRoundContext":
-        """Precompute the per-cut tables (same accounting as
-        ``BatchedRoundContext.build``) and stack the tier's per-server
-        scalars into ``(S,)`` arrays."""
+        """Precompute the float64 per-cut tables, the per-device memory cap
+        and the tier's per-server scalars as ``(S,)`` arrays."""
         compute = resolve_compute(workload, cost_source, latency_table)
-        tables = _per_cut_tables(workload, sim, compute)
+        cuts = range(workload.cfg.n_layers + 1)
         arrs = fleet_arrays(devices)
         srv = tier_arrays(tier)
-        max_cut = _max_cut_per_device(tables["weight_bytes"],
-                                      arrs["mem_bytes"])
+        # memory feasibility: the largest c whose frozen device-side weights
+        # fit MEM_BUDGET_FRACTION of device RAM (0 when not even the
+        # embedding fits)
+        weight_bytes = np.array([workload.device_weight_bytes(c)
+                                 for c in cuts])
+        feas = (weight_bytes[None, :]
+                <= MEM_BUDGET_FRACTION * arrs["mem_bytes"][:, None])  # (D, C)
+        max_cut = np.where(feas.any(axis=1),
+                           feas.shape[1] - 1 - np.argmax(feas[:, ::-1], axis=1),
+                           0)
         return cls(
-            dev_flops=jnp.asarray(tables["dev_flops"]),
-            srv_flops=jnp.asarray(tables["srv_flops"]),
-            up_bits=jnp.asarray(tables["up_bits"]),
-            down_bits=jnp.asarray(tables["down_bits"]),
-            adapter_bits=jnp.asarray(tables["adapter_bits"]),
+            dev_flops=jnp.asarray(np.array(
+                [compute.device_flops(c) for c in cuts])),
+            srv_flops=jnp.asarray(np.array(
+                [compute.server_flops(c) for c in cuts])),
+            up_bits=jnp.asarray(np.array(
+                [8 * sim.phi * workload.smashed_bytes(c, sim.act_bytes)
+                 for c in cuts])),
+            down_bits=jnp.asarray(np.array(
+                [8 * sim.phi * workload.gradient_bytes(c, sim.act_bytes)
+                 for c in cuts])),
+            adapter_bits=jnp.asarray(np.array(
+                [8 * workload.adapter_bytes(c, sim.adapter_bytes)
+                 for c in cuts])),
             peak_flops=jnp.asarray(arrs["peak_flops"]),
             max_cut=jnp.asarray(max_cut, jnp.int32),
             rate_up=jnp.asarray(channels.rate_up),

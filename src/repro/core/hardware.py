@@ -93,8 +93,8 @@ class ServerTier:
 
     ``hierarchical_card`` (``core/card.py``) decides device→server
     assignment against this structure; ``TieredRoundContext``
-    (``core/cost_model.py``) broadcasts Eqs. 7-12 over the extra server
-    axis.
+    (``core/cost_model.py``) broadcasts Eqs. 7-12 over the server axis.
+    The paper's single edge server is the one-server tier.
     """
     servers: Tuple[DeviceProfile, ...]
     capacity: Tuple[int, ...]
@@ -143,7 +143,7 @@ def make_server_tier(n: int, *, base: DeviceProfile = SERVER_RTX4060TI,
 
 
 def tier_arrays(tier: ServerTier) -> Dict[str, "object"]:
-    """Stack per-server scalars into numpy arrays for the tiered engine."""
+    """Stack per-server scalars into numpy arrays for the array engine."""
     return {
         "tp_per_hz": np.array([s.delta * s.sigma for s in tier.servers],
                               np.float64),
@@ -169,14 +169,14 @@ def profile_from_throughput(name: str, flops_per_s: float, *,
 
 
 def fleet_arrays(devices) -> Dict[str, "object"]:
-    """Stack per-device scalars into numpy arrays for the batched engine."""
+    """Stack per-device scalars into numpy arrays for the array engine."""
     return {
         "peak_flops": np.array([d.peak_flops for d in devices], np.float64),
         "mem_bytes": np.array([d.mem_bytes for d in devices], np.float64),
     }
 
 
-# --- TPU v5e server profile (multi-pod mapping, DESIGN.md §3) --------------
+# --- TPU v5e server profile (multi-pod mapping) ----------------------------
 # The paper's continuous f^S maps to allocated server throughput. One v5e
 # chip: 197 TFLOP/s bf16. We express it in the same (f, delta, sigma) algebra
 # so CARD's closed form applies unchanged.

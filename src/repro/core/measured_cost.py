@@ -16,7 +16,7 @@ paper's analytic FLOP counts.  This module is the bridge:
                              predicted from the fit (or synthesized from
                              the analytic model), the pluggable backend
                              ``cost_model.RoundContext`` /
-                             ``BatchedRoundContext`` consume via
+                             ``TieredRoundContext`` consume via
                              ``cost_source="measured"``.
 
 The currency trick: a ``LatencyTable`` stores *seconds at a reference
@@ -430,7 +430,7 @@ class TableCompute:
 
     The interface ``cost_model.resolve_compute`` expects: ``device_flops``,
     ``server_flops``, ``total_flops`` — drop-in for ``AnalyticCompute``, so
-    ``card``/``batched_card`` decide on measured numbers transparently.
+    ``card``/``tiered_card_grid`` decide on measured numbers transparently.
     """
     workload: Workload
     table: LatencyTable

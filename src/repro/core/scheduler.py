@@ -3,14 +3,14 @@ paper's figures, which need many rounds x devices x policies cheaply.
 
 ``simulate_fleet`` reproduces the experiment grid of Sec. V: per round, per
 device, draw a channel state, run the policy, log (cut, f, delay, energy).
-The numbers feed Fig. 3 / Fig. 4 style benchmarks and the EXPERIMENTS.md
-validation against the paper's 70.8% / 53.1% claims.
+The numbers feed Fig. 3 / Fig. 4 style benchmarks and the validation
+against the paper's 70.8% / 53.1% claims (``benchmarks/fig4_comparison.py``).
 
 Two engines share one cost model:
 
   engine="vectorized" (default) — all channel states drawn up front
-      ((rounds, devices) batch), then the whole (rounds, devices, cuts)
-      decision grid runs under jax.jit via ``card.batched_card``. This is
+      ((rounds, devices) batch), then the whole decision grid runs under
+      jax.jit via ``card.tiered_card_grid`` on a one-server tier. This is
       the path that scales to thousand-device heterogeneous fleets.
   engine="scalar" — the original per-(round, device) Python loop, kept as
       the reference oracle; both engines consume identical channel
@@ -19,6 +19,7 @@ Two engines share one cost model:
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -28,10 +29,10 @@ from repro.configs.base import ModelConfig
 from repro.core import card as card_lib
 from repro.core.channel import (SEED_STRIDE, WirelessChannel,
                                 draw_channel_matrix)
-from repro.core.cost_model import BatchedRoundContext, RoundContext, Workload
+from repro.core.cost_model import RoundContext, TieredRoundContext, Workload
 from repro.core.faults import DeadlinePolicy, FaultModel, FaultRealization
 from repro.core.hardware import (DEFAULT_SIM, EDGE_FLEET, SERVER_RTX4060TI,
-                                 DeviceProfile, SimParams)
+                                 DeviceProfile, ServerTier, SimParams)
 
 
 def _masked_mean(a: np.ndarray) -> float:
@@ -151,54 +152,23 @@ def _simulate_fleet_scalar(cfg: ModelConfig, *, policy: str,
                     freqs=freqs, delays=delays, energies=energies, **parts)
 
 
-def _simulate_fleet_vectorized(cfg: ModelConfig, *, policy: str,
-                               channel_state: str, rounds: int,
-                               devices: Sequence[DeviceProfile],
-                               server: DeviceProfile, sim: SimParams,
-                               seed: int, static_cut: Optional[int],
-                               respect_memory: bool, cost_source: str,
-                               latency_table, deadline_spec) -> FleetLog:
-    """All channel states up front, one jitted grid evaluation per policy."""
-    nd = len(devices)
-    batch = draw_channel_matrix(channel_state, rounds, nd, seed=seed,
-                                bandwidth_hz=sim.bandwidth_hz,
-                                tx_power_dbm_up=sim.tx_power_dbm_up,
-                                tx_power_dbm_down=sim.tx_power_dbm_down,
-                                noise_dbm_per_hz=sim.noise_dbm_per_hz)
-    workload = Workload(cfg, sim.mini_batch, sim.seq_len)
-    bctx = BatchedRoundContext.build(workload, devices, server, batch, sim,
-                                     cost_source=cost_source,
-                                     latency_table=latency_table)
+def _decide(tctx: TieredRoundContext, cut_draws, *, policy: str,
+            static_cut: Optional[int], respect_memory: bool,
+            deadline_spec) -> "card_lib.TierDecision":
+    """One policy over the whole (servers, rounds, devices) grid."""
     if policy == "card":
-        dec = card_lib.batched_card(bctx, respect_memory=respect_memory,
-                                    deadline=deadline_spec)
-    elif policy == "server_only":
-        dec = card_lib.batched_server_only(bctx)
-    elif policy == "device_only":
-        dec = card_lib.batched_device_only(bctx)
-    elif policy == "static":
+        return card_lib.tiered_card_grid(tctx, respect_memory=respect_memory,
+                                         deadline=deadline_spec)
+    if policy == "server_only":
+        return card_lib.tiered_server_only(tctx)
+    if policy == "device_only":
+        return card_lib.tiered_device_only(tctx)
+    if policy == "static":
         assert static_cut is not None
-        dec = card_lib.batched_static_cut(bctx, static_cut)
-    elif policy == "random":
-        # same stream the scalar loop consumes for its per-decision draws
-        rng = np.random.default_rng(seed)
-        draws = rng.integers(0, cfg.n_layers + 1, size=(rounds, nd))
-        dec = card_lib.batched_static_cut(bctx, draws)
-    else:
-        raise ValueError(f"unknown policy {policy!r}")
-    # one device_get for the whole decision pytree instead of eight
-    # separate device->host transfers
-    host = jax.device_get(dec)
-    return FleetLog(policy=policy, channel_state=channel_state, rounds=rounds,
-                    device_names=[d.name for d in devices],
-                    cuts=np.asarray(host.cuts, np.int32),
-                    freqs=np.asarray(host.freqs, np.float64),
-                    delays=np.asarray(host.delays, np.float64),
-                    energies=np.asarray(host.energies, np.float64),
-                    d_device=np.asarray(host.d_device, np.float64),
-                    d_uplink=np.asarray(host.d_uplink, np.float64),
-                    d_server=np.asarray(host.d_server, np.float64),
-                    d_downlink=np.asarray(host.d_downlink, np.float64))
+        return card_lib.tiered_static_cut(tctx, static_cut)
+    if policy == "random":
+        return card_lib.tiered_static_cut(tctx, cut_draws)
+    raise ValueError(f"unknown policy {policy!r}")
 
 
 def _shard_pad(a: np.ndarray, pad: int, value) -> np.ndarray:
@@ -213,24 +183,29 @@ def _shard_pad(a: np.ndarray, pad: int, value) -> np.ndarray:
     return np.pad(np.asarray(a), width, constant_values=value)
 
 
-def _simulate_fleet_sharded(cfg: ModelConfig, *, mesh, policy: str,
-                            channel_state: str, rounds: int,
-                            devices: Sequence[DeviceProfile],
-                            server: DeviceProfile, sim: SimParams,
-                            seed: int, static_cut: Optional[int],
-                            respect_memory: bool, cost_source: str,
-                            latency_table, deadline_spec) -> FleetLog:
-    """The vectorized engine with the *devices* axis sharded over a 1-D
-    ``("data",)`` mesh — one ``jit(shard_map(...))`` call for the whole
-    fleet, the 10^6-device path.
+def _simulate_fleet_array(cfg: ModelConfig, *, mesh, policy: str,
+                          channel_state: str, rounds: int,
+                          devices: Sequence[DeviceProfile],
+                          server: DeviceProfile, sim: SimParams, seed: int,
+                          static_cut: Optional[int], respect_memory: bool,
+                          cost_source: str, latency_table,
+                          deadline_spec) -> FleetLog:
+    """All channel states up front, one jitted grid evaluation per policy.
 
-    Bit-identical to ``engine="vectorized"`` on one host: every per-lane
-    quantity in the (rounds, devices, cuts) grid — corners, Eq. 16 f*, the
-    argmin over cuts — is computed from that device's own lane (no
-    cross-device reduction anywhere in ``batched_card``), so sharding the
-    axis changes data placement, never values. Channel draws stay on the
-    host (same ``draw_channel_matrix`` stream), devices are padded to a
-    shard multiple with dummy lanes and trimmed off the result.
+    The paper's one edge server is a one-server tier hosting the whole
+    fleet (its backhaul is priced only by ``hierarchical_card``, never
+    here); the log is that server's lane of the (1, R, D) decision.
+
+    With ``mesh`` the *devices* axis is sharded over a 1-D ``("data",)``
+    mesh — one ``shard_map`` call for the whole fleet, the 10^6-device
+    path — bit-identical to ``mesh=None``: every per-lane quantity in the
+    grid — corners, Eq. 16 f*, the argmin over cuts — is computed from
+    that device's own lane (no cross-device reduction anywhere in
+    ``tiered_card_grid``), so sharding the axis changes data placement,
+    never values. Channel draws stay on the host (same
+    ``draw_channel_matrix`` stream), the server's ``(1,)`` arrays are
+    replicated, and devices are padded to a shard multiple with dummy
+    lanes and trimmed off the result.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -240,66 +215,57 @@ def _simulate_fleet_sharded(cfg: ModelConfig, *, mesh, policy: str,
                                 tx_power_dbm_up=sim.tx_power_dbm_up,
                                 tx_power_dbm_down=sim.tx_power_dbm_down,
                                 noise_dbm_per_hz=sim.noise_dbm_per_hz)
-    workload = Workload(cfg, sim.mini_batch, sim.seq_len)
-    bctx = BatchedRoundContext.build(workload, devices, server, batch, sim,
-                                     cost_source=cost_source,
-                                     latency_table=latency_table)
-    n_shards = int(np.prod(mesh.devices.shape))
-    pad = (-nd) % n_shards
-    bctx = dataclasses.replace(
-        bctx,
-        peak_flops=_shard_pad(bctx.peak_flops, pad, 1.0),
-        max_cut=_shard_pad(bctx.max_cut, pad, 0),
-        rate_up=_shard_pad(bctx.rate_up, pad, 1.0),
-        rate_down=_shard_pad(bctx.rate_down, pad, 1.0))
-    # same pytree, PartitionSpec leaves: tables/weights replicated, every
-    # device-axis field sharded on "data"
-    specs = dataclasses.replace(
-        bctx, dev_flops=P(), srv_flops=P(), up_bits=P(), down_bits=P(),
-        adapter_bits=P(), peak_flops=P("data"), max_cut=P("data"),
-        rate_up=P(None, "data"), rate_down=P(None, "data"), w=P(), xi=P())
-    if policy == "random":
-        rng = np.random.default_rng(seed)
-        draws = rng.integers(0, cfg.n_layers + 1, size=(rounds, nd))
+    tier = ServerTier(servers=(server,), capacity=(nd,),
+                      backhaul_bits_per_s=(1.0,))
+    tctx = TieredRoundContext.build(
+        Workload(cfg, sim.mini_batch, sim.seq_len), devices, tier, batch,
+        sim, cost_source=cost_source, latency_table=latency_table)
+    # the random policy's cuts: the same stream the scalar loop consumes
+    # for its per-decision draws
+    draws = (np.random.default_rng(seed).integers(
+        0, cfg.n_layers + 1, size=(rounds, nd)) if policy == "random"
+        else np.zeros((rounds, nd), np.int64))
+    decide = partial(_decide, policy=policy, static_cut=static_cut,
+                     respect_memory=respect_memory,
+                     deadline_spec=deadline_spec)
+    if mesh is None:
+        dec = decide(tctx, draws)
     else:
-        draws = np.zeros((rounds, nd), np.int64)
-    draws = _shard_pad(draws, pad, 0)
-
-    def _decide(ctx, cut_draws):
-        if policy == "card":
-            return card_lib.batched_card(ctx, respect_memory=respect_memory,
-                                         deadline=deadline_spec)
-        if policy == "server_only":
-            return card_lib.batched_server_only(ctx)
-        if policy == "device_only":
-            return card_lib.batched_device_only(ctx)
-        if policy in ("static", "random"):
-            cut = static_cut if policy == "static" else cut_draws
-            return card_lib.batched_static_cut(ctx, cut)
-        raise ValueError(f"unknown policy {policy!r}")
-
-    # eager shard_map (no outer jit): the policy fns are already jitted, so
-    # each shard runs the *same compiled executable* as the unsharded
-    # engine — wrapping the shard_map in another jit would inline that jit
-    # and let XLA re-fuse the grid differently (one-ulp drift in the logs),
-    # breaking the bit-identity contract this engine is tested against
-    sharded = jax.shard_map(_decide, mesh=mesh,
+        pad = (-nd) % int(np.prod(mesh.devices.shape))
+        tctx = dataclasses.replace(
+            tctx,
+            peak_flops=_shard_pad(tctx.peak_flops, pad, 1.0),
+            max_cut=_shard_pad(tctx.max_cut, pad, 0),
+            rate_up=_shard_pad(tctx.rate_up, pad, 1.0),
+            rate_down=_shard_pad(tctx.rate_down, pad, 1.0))
+        # same pytree, PartitionSpec leaves: tables, server arrays and
+        # weights replicated, every device-axis field sharded on "data"
+        specs = dataclasses.replace(
+            tctx, dev_flops=P(), srv_flops=P(), up_bits=P(), down_bits=P(),
+            adapter_bits=P(), peak_flops=P("data"), max_cut=P("data"),
+            rate_up=P(None, "data"), rate_down=P(None, "data"),
+            server_tp_per_hz=P(), server_f_max=P(), server_f_min=P(),
+            backhaul_bits_per_s=P(), w=P(), xi=P())
+        # eager shard_map (no outer jit): the policy fns are already
+        # jitted, so each shard runs the *same compiled executable* as the
+        # unsharded engine — wrapping the shard_map in another jit would
+        # inline that jit and let XLA re-fuse the grid differently (one-ulp
+        # drift in the logs), breaking the bit-identity contract this
+        # engine is tested against
+        dec = jax.shard_map(decide, mesh=mesh,
                             in_specs=(specs, P(None, "data")),
-                            out_specs=P(None, "data"))
-    host = jax.device_get(sharded(bctx, draws))
-    trim = {f: np.asarray(getattr(host, f))[:, :nd]
-            for f in ("cuts", "freqs", "delays", "energies",
-                      "d_device", "d_uplink", "d_server", "d_downlink")}
+                            out_specs=P(None, None, "data"))(
+            tctx, _shard_pad(draws, pad, 0))
+    # one device_get for the whole decision pytree
+    host = jax.device_get(dec)
+    lane = {f: np.asarray(getattr(host, f))[0, :, :nd]
+            for f in card_lib.TierDecision._fields}
     return FleetLog(policy=policy, channel_state=channel_state, rounds=rounds,
                     device_names=[d.name for d in devices],
-                    cuts=trim["cuts"].astype(np.int32),
-                    freqs=trim["freqs"].astype(np.float64),
-                    delays=trim["delays"].astype(np.float64),
-                    energies=trim["energies"].astype(np.float64),
-                    d_device=trim["d_device"].astype(np.float64),
-                    d_uplink=trim["d_uplink"].astype(np.float64),
-                    d_server=trim["d_server"].astype(np.float64),
-                    d_downlink=trim["d_downlink"].astype(np.float64))
+                    cuts=lane["cuts"].astype(np.int32),
+                    **{f: lane[f].astype(np.float64)
+                       for f in ("freqs", "delays", "energies", "d_device",
+                                 "d_uplink", "d_server", "d_downlink")})
 
 
 def apply_faults(log: FleetLog, realization: FaultRealization,
@@ -395,7 +361,7 @@ def simulate_fleet(cfg: ModelConfig, *, policy: str = "card",
 
     ``mesh`` (a 1-D ``("data",)`` mesh from ``launch.mesh.make_fleet_mesh``)
     shards the devices axis of the vectorized engine across host devices in
-    one ``jit(shard_map(...))`` call — bit-identical to the unsharded
+    one ``shard_map`` call — bit-identical to the unsharded
     vectorized engine, scales the sweep to 10^6 devices.
     """
     deadline_spec = None
@@ -411,13 +377,11 @@ def simulate_fleet(cfg: ModelConfig, *, policy: str = "card",
                   static_cut=static_cut, respect_memory=respect_memory,
                   cost_source=cost_source, latency_table=latency_table,
                   deadline_spec=deadline_spec)
-    if mesh is not None:
-        if engine != "vectorized":
-            raise ValueError(f"mesh= requires engine='vectorized', "
-                             f"got {engine!r}")
-        log = _simulate_fleet_sharded(cfg, mesh=mesh, **kwargs)
-    elif engine == "vectorized":
-        log = _simulate_fleet_vectorized(cfg, **kwargs)
+    if mesh is not None and engine != "vectorized":
+        raise ValueError(f"mesh= requires engine='vectorized', "
+                         f"got {engine!r}")
+    if engine == "vectorized":
+        log = _simulate_fleet_array(cfg, mesh=mesh, **kwargs)
     elif engine == "scalar":
         log = _simulate_fleet_scalar(cfg, **kwargs)
     else:
@@ -534,8 +498,6 @@ def simulate_hierarchical_fleet(cfg: ModelConfig, *,
     (same stream as the flat engines), run :func:`card.hierarchical_card`
     against the :class:`hardware.ServerTier`, and fold per-server parallel
     round times with the backhaul aggregation stage."""
-    from repro.core.cost_model import TieredRoundContext
-
     batch = draw_channel_matrix(channel_state, rounds, len(devices),
                                 seed=seed, bandwidth_hz=sim.bandwidth_hz,
                                 tx_power_dbm_up=sim.tx_power_dbm_up,

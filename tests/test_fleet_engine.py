@@ -1,6 +1,6 @@
 """Vectorized CARD fleet engine vs the scalar reference oracle.
 
-The batched path must be a pure refactor of the decision layer: identical
+The array path must be a pure refactor of the decision layer: identical
 channel realizations in, identical (cut, frequency) decisions out, for every
 policy, architecture, and channel state — plus the new exact parallel-SL
 round time must land inside the legacy upper/lower bounds.
@@ -13,13 +13,21 @@ from repro.configs.base import get_config
 from repro.core import card as C
 from repro.core.channel import (SEED_STRIDE, WirelessChannel,
                                 draw_channel_matrix)
-from repro.core.cost_model import BatchedRoundContext, RoundContext, Workload
+from repro.core.cost_model import RoundContext, TieredRoundContext, Workload
 from repro.core.hardware import (DEFAULT_SIM, EDGE_FLEET, SERVER_RTX4060TI,
-                                 SimParams, make_heterogeneous_fleet)
+                                 ServerTier, SimParams,
+                                 make_heterogeneous_fleet)
 from repro.core.scheduler import parallel_round_stats, simulate_fleet
 
 ARCHS = ("llama32-1b", "qwen3-4b", "granite-moe-3b-a800m")
 STATES = ("good", "normal", "poor")
+
+
+def _one_server_ctx(workload, devices, batch, sim):
+    """The paper's single edge server as a one-server tier."""
+    tier = ServerTier(servers=(SERVER_RTX4060TI,), capacity=(len(devices),),
+                      backhaul_bits_per_s=(1e9,))
+    return TieredRoundContext.build(workload, devices, tier, batch, sim)
 
 
 def _assert_logs_match(a, b):
@@ -124,30 +132,29 @@ def test_batched_card_beats_joint_grid():
                                 tx_power_dbm_up=sim.tx_power_dbm_up,
                                 tx_power_dbm_down=sim.tx_power_dbm_down,
                                 noise_dbm_per_hz=sim.noise_dbm_per_hz)
-    bctx = BatchedRoundContext.build(
-        Workload(cfg, sim.mini_batch, sim.seq_len), EDGE_FLEET,
-        SERVER_RTX4060TI, batch, sim)
-    a = C.batched_card(bctx)
-    g = C.batched_card_joint_bruteforce(bctx, n_freq=60)
+    tctx = _one_server_ctx(Workload(cfg, sim.mini_batch, sim.seq_len),
+                           EDGE_FLEET, batch, sim)
+    a = C.tiered_card_grid(tctx)
+    g = C.tiered_card_joint_bruteforce(tctx, n_freq=60)
+    assert a.cuts.shape == g.cuts.shape == (1, 3, len(EDGE_FLEET))
     assert np.all(np.asarray(a.costs) <= np.asarray(g.costs) + 1e-5)
     assert np.array_equal(np.asarray(a.cuts), np.asarray(g.cuts))
 
 
 def test_memory_mask_batched_matches_scalar():
-    """A 1T-param model can't fit any Jetson: every batched decision must
+    """A 1T-param model can't fit any Jetson: every array decision must
     respect the same per-device feasibility cap the scalar path derives."""
     kimi = get_config("kimi-k2-1t-a32b")
     sim = SimParams(mini_batch=1, seq_len=128)
     w = Workload(kimi, 1, 128)
     batch = draw_channel_matrix("normal", 2, len(EDGE_FLEET), seed=0,
                                 bandwidth_hz=sim.bandwidth_hz)
-    bctx = BatchedRoundContext.build(w, EDGE_FLEET, SERVER_RTX4060TI, batch,
-                                     sim)
+    tctx = _one_server_ctx(w, EDGE_FLEET, batch, sim)
     for m, dev in enumerate(EDGE_FLEET):
         ctx = RoundContext(workload=w, device=dev, server=SERVER_RTX4060TI,
                            channel=batch.state(0, m), sim=sim)
-        assert int(bctx.max_cut[m]) == ctx.max_feasible_cut()
-    dec = C.batched_card(bctx)
+        assert int(tctx.max_cut[m]) == ctx.max_feasible_cut()
+    dec = C.tiered_card_grid(tctx)
     assert np.all(np.asarray(dec.cuts) == 0)
 
 

@@ -1,13 +1,13 @@
-"""Hierarchical multi-server CARD: assignment optimality, scalar-vs-batched
-decision equivalence, and the S=1 degenerate case collapsing to the flat
-batched engine."""
+"""Hierarchical multi-server CARD: assignment optimality, scalar-vs-array
+decision equivalence, and the S=1 degenerate case collapsing to the
+paper's single-server CARD."""
 import numpy as np
 import pytest
 
 from repro.configs.base import get_config
 from repro.core import card as C
 from repro.core.channel import draw_channel_matrix
-from repro.core.cost_model import (BatchedRoundContext, TieredRoundContext,
+from repro.core.cost_model import (RoundContext, TieredRoundContext,
                                    Workload)
 from repro.core.hardware import (DEFAULT_SIM, EDGE_FLEET, SERVER_RTX4060TI,
                                  ServerTier, make_heterogeneous_fleet,
@@ -57,14 +57,14 @@ def test_make_server_tier_heterogeneous():
     assert (arrs["backhaul_bits_per_s"] > 0).all()
 
 
-# --- TieredRoundContext vs flat BatchedRoundContext --------------------------
+# --- a one-server tier vs the scalar single-server CARD ----------------------
 
 
 def test_s1_tier_matches_flat_batched_context():
-    """A 1-server tier is exactly the paper's single-server problem: the
-    tiered grid must reproduce batched_card's decisions bit-for-bit (the
-    metric tensors carry an extra server axis, so their float32 sums may
-    contract one ulp apart — decisions, not roundoff, are the contract)."""
+    """A 1-server tier is exactly the paper's single-server problem: its
+    grid must give the float64 scalar ``card``'s cut on every (round,
+    device) lane, with frequencies, costs, delays and energies to float32
+    resolution (decisions, not roundoff, are the contract)."""
     cfg = get_config("llama32-1b")
     sim = DEFAULT_SIM
     wl = Workload(cfg, sim.mini_batch, sim.seq_len)
@@ -74,17 +74,28 @@ def test_s1_tier_matches_flat_batched_context():
     tier = ServerTier(servers=(SERVER_RTX4060TI,), capacity=(4,),
                       backhaul_bits_per_s=(1e9,))
     tctx = TieredRoundContext.build(wl, devices, tier, ch, sim)
-    bctx = BatchedRoundContext.build(wl, devices, SERVER_RTX4060TI, ch, sim)
     h = C.hierarchical_card(tctx)
-    b = C.batched_card(bctx)
+    grid = C.tiered_card_grid(tctx)
     assert (h.assignment == 0).all()
-    np.testing.assert_array_equal(np.asarray(h.cuts), np.asarray(b.cuts))
-    np.testing.assert_array_equal(np.asarray(h.freqs), np.asarray(b.freqs))
-    np.testing.assert_array_equal(np.asarray(h.costs), np.asarray(b.costs))
-    np.testing.assert_allclose(np.asarray(h.delays), np.asarray(b.delays),
-                               rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(h.energies),
-                               np.asarray(b.energies), rtol=1e-6)
+    assert grid.cuts.shape == (1, 3, 4)
+    # the assignment readout of one server is that server's lane
+    for field in ("cuts", "freqs", "costs", "delays", "energies", "d_server"):
+        np.testing.assert_array_equal(np.asarray(getattr(h, field)),
+                                      np.asarray(getattr(grid, field))[0])
+    for r in range(3):
+        for m, dev in enumerate(devices):
+            d = C.card(RoundContext(workload=wl, device=dev,
+                                    server=SERVER_RTX4060TI,
+                                    channel=ch.state(r, m), sim=sim))
+            assert int(grid.cuts[0, r, m]) == d.cut, (r, m)
+            np.testing.assert_allclose(float(grid.freqs[0, r, m]),
+                                       d.frequency, rtol=1e-5)
+            np.testing.assert_allclose(float(grid.costs[0, r, m]), d.cost,
+                                       rtol=1e-4, atol=1e-6)
+            np.testing.assert_allclose(float(grid.delays[0, r, m]), d.delay,
+                                       rtol=1e-4)
+            np.testing.assert_allclose(float(grid.energies[0, r, m]),
+                                       d.energy, rtol=1e-4, atol=1e-6)
 
 
 def test_tiered_grid_shape_and_masking():

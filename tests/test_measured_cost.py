@@ -3,7 +3,7 @@ that the "measured" backend is a strict generalization of the analytic one.
 
 The load-bearing property: a ``LatencyTable`` synthesized *from* the
 analytic model (``from_analytic``) must reproduce the analytic
-``card``/``batched_card`` decisions exactly — same cuts, same Eq. 16
+``card``/``tiered_card_grid`` decisions exactly — same cuts, same Eq. 16
 frequencies, same delays/energies — across architectures, channel states,
 and both fleet engines. Everything the measured path changes is then
 attributable to the calibration, not to the plumbing.
@@ -12,10 +12,10 @@ import numpy as np
 import pytest
 
 from repro.configs.base import get_config
-from repro.core.cost_model import (BatchedRoundContext, RoundContext,
+from repro.core.cost_model import (RoundContext, TieredRoundContext,
                                    Workload, resolve_compute)
 from repro.core.hardware import (DEFAULT_SIM, EDGE_FLEET, SERVER_RTX4060TI,
-                                 profile_from_throughput)
+                                 ServerTier, profile_from_throughput)
 from repro.core.measured_cost import (LatencyTable, ProbeResult, RooflineFit,
                                       TableCompute, build_latency_tables,
                                       fit_roofline)
@@ -54,7 +54,7 @@ def _assert_logs_match(a, b):
 @pytest.mark.parametrize("state", STATES)
 @pytest.mark.parametrize("arch", ARCHS)
 def test_measured_reproduces_analytic_decisions(arch, state):
-    """batched_card on an analytic-synthesized table == pure analytic."""
+    """The array grid on an analytic-synthesized table == pure analytic."""
     cfg = get_config(arch)
     kw = dict(channel_state=state, rounds=5, seed=3, respect_memory=False)
     a = simulate_fleet(cfg, **kw)
@@ -90,7 +90,7 @@ def test_analytic_table_flops_match_workload_exactly():
 
 
 def test_measured_batched_card_end_to_end():
-    """A calibrated (synthetic-fit) table runs through batched_card and
+    """A calibrated (synthetic-fit) table runs through the array grid and
     produces sane decisions: valid cuts, clipped frequencies, finite costs."""
     cfg = get_config("llama32-1b")
     table = LatencyTable.from_fit(cfg, _synthetic_fit(), batch=BATCH,
@@ -127,12 +127,13 @@ def test_batched_context_build_measured():
     w = Workload(get_config("llama32-1b"), BATCH, SEQ)
     from repro.core.channel import draw_channel_matrix
     chans = draw_channel_matrix("normal", 2, len(EDGE_FLEET), seed=0)
-    b_a = BatchedRoundContext.build(w, EDGE_FLEET, SERVER_RTX4060TI, chans,
-                                    DEFAULT_SIM)
-    b_m = BatchedRoundContext.build(w, EDGE_FLEET, SERVER_RTX4060TI, chans,
-                                    DEFAULT_SIM, cost_source="measured",
-                                    latency_table=_analytic_table(
-                                        "llama32-1b"))
+    tier = ServerTier(servers=(SERVER_RTX4060TI,),
+                      capacity=(len(EDGE_FLEET),), backhaul_bits_per_s=(1e9,))
+    b_a = TieredRoundContext.build(w, EDGE_FLEET, tier, chans, DEFAULT_SIM)
+    b_m = TieredRoundContext.build(w, EDGE_FLEET, tier, chans, DEFAULT_SIM,
+                                   cost_source="measured",
+                                   latency_table=_analytic_table(
+                                       "llama32-1b"))
     np.testing.assert_allclose(np.asarray(b_m.dev_flops),
                                np.asarray(b_a.dev_flops), rtol=1e-6)
     np.testing.assert_allclose(np.asarray(b_m.srv_flops),
